@@ -1,45 +1,28 @@
-"""Device rapid-vs-baseline epoch benchmark (paper Table 2, device path).
+"""Device rapid-vs-baseline epoch rows (paper Table 2, device path).
 
-Thin campaign wrapper: the two device-backend cells of the campaign's
-fast grid (``repro.eval.spec.fast_grid``) run through the SAME
-subprocess machinery the campaign uses (``repro.eval.cells.
-run_device_cells`` -- the device count locks at first jax init, so the
-cells execute in a child pinned to 4 emulated host devices), and the
-rows below are formatted from their unified ``CellResult`` records.
-Step time excludes the compile epoch; lane counts are the exact
-residual-miss accounting the campaign's ``miss_parity`` differential
-check pins to the host-sim runners.
+Formats the device-backend cells of a campaign run (``repro.eval.
+campaign``; ``benchmarks.run`` passes its ``paper_campaign`` section's
+cells, so the SPMD child runs once per invocation) into CSV rows. Step
+time excludes the compile epoch; lane counts are the exact residual-miss
+accounting the campaign's ``miss_parity`` differential check pins to the
+host-sim runners.
 
 Caveat: on EMULATED host devices the all_to_all is a shared-memory copy,
 so the step-time ratio does not show the paper's network win -- the
 miss-lane / payload columns carry that signal (9.7-15.4x fewer remote
 fetches at paper scale; ~2-3x on the tiny graph), and step time becomes
 meaningful on a real mesh where the pull has wire latency to hide.
-
-``python -m benchmarks.device_epoch``   -- runs the cells, prints rows
 """
 from __future__ import annotations
 
-import argparse
 from typing import List
 
 HEADER = ("system,workers,epochs,steps,step_time_ms,"
           "miss_lanes_per_epoch,payload_kb,wire_rows")
 
 
-def run(epochs: int = 3, results=None) -> List[str]:
-    """``results`` short-circuits measurement with already-run device
-    ``CellResult``s (benchmarks.run passes the paper_campaign section's
-    cells so the expensive SPMD subprocess runs once per invocation)."""
-    import dataclasses
-
-    from repro.eval.cells import run_device_cells
-    from repro.eval.spec import fast_grid
-
-    if results is None:
-        cells = [dataclasses.replace(c, epochs=epochs)
-                 for c in fast_grid().device_cells()]
-        results = run_device_cells(cells)
+def run(results) -> List[str]:
+    """-> CSV rows for the device ``CellResult``s of one campaign run."""
     rows = [HEADER]
     step_ms = {}
     for c in results:
@@ -60,14 +43,3 @@ def run(epochs: int = 3, results=None) -> List[str]:
                 f"{results[0].spec['epochs']},-,{speedup:.2f}x,-,-,-")
     return rows
 
-
-def main() -> None:
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--epochs", type=int, default=3)
-    args = ap.parse_args()
-    for row in run(epochs=args.epochs):
-        print(row)
-
-
-if __name__ == "__main__":
-    main()

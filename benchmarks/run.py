@@ -4,21 +4,29 @@
 ``python -m benchmarks.run --full`` -- paper-scale grids (slow)
 
 Prints CSV blocks per benchmark plus a ``name,us_per_call,derived``
-summary line per section (harness contract), and writes EVERY section
+summary line per section (harness contract), exits non-zero when any
+section failed, and writes EVERY section
 as machine-readable JSON to ``artifacts/BENCH_<name>.json``:
 ``{"section", "status", "us", "summary", "rows"}`` -- the rows split
 into header/records when the first row is a CSV header. Sections with
 richer native records (assemble) additionally write their own files,
 and the cross-backend paper grid lives in ``BENCH_paper.json``
 (``python -m repro.eval.campaign``, DESIGN.md §7).
+
+The paper campaign runs FIRST: its device cells run in child processes,
+which need the accelerator that this process holds once a section has
+run JAX in-process.
 """
 from __future__ import annotations
 
 import argparse
 import json
 import os
+import sys
 import time
 import traceback
+
+from repro.compile_cache import enable_compile_cache
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ART = os.path.join(ROOT, "artifacts")
@@ -38,7 +46,9 @@ def _write_section_json(name, status, us, summary, rows):
         json.dump(rec, f, indent=1)
 
 
-def _section(name, fn, summary):
+def _section(name, fn, summary, failed):
+    """Run one section; a failure is printed, recorded in its JSON and
+    appended to ``failed`` so the process exits non-zero."""
     print(f"\n===== {name} =====")
     t0 = time.time()
     try:
@@ -50,18 +60,21 @@ def _section(name, fn, summary):
         print(f"#summary {name},{dt:.0f},{s}")
         _write_section_json(name, "ok", dt, str(s), list(rows))
         return rows
-    except Exception as e:
+    except Exception as e:      # one section's failure must not stop the rest
         print(f"#summary {name},0,FAILED:{e}")
         traceback.print_exc()
         _write_section_json(name, "failed", 0.0, f"FAILED:{e}", [])
+        failed.append(name)
         return []
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--skip-roofline", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
+    failed: list = []
 
     from benchmarks import (speedup, access_dist, comm_volume, cache_sweep,
                             scaling, memory, energy, convergence,
@@ -77,36 +90,6 @@ def main() -> None:
         bs = (100, 200)
         epochs = 2
 
-    _section("table2_speedup",
-             lambda: speedup.run(datasets=ds, batch_sizes=bs,
-                                 epochs=epochs),
-             lambda rows: rows[-1] if rows else "-")
-    _section("fig3_access_distribution", access_dist.run,
-             lambda rows: next((r for r in rows if "once" in r), "-"))
-    _section("fig4_comm_volume",
-             lambda: comm_volume.run(datasets=ds, batch_sizes=bs,
-                                     epochs=epochs),
-             lambda rows: rows[-1] if rows else "-")
-    _section("fig5_cache_sweep",
-             lambda: cache_sweep.run(batch_sizes=bs[:1]),
-             lambda rows: rows[-1] if rows else "-")
-    # raises (-> section FAILED) on a broken intra+inter byte-sum
-    # identity or a DCN bias that raises cross-host traffic
-    _section("topology",
-             lambda: topology.run(datasets=ds, batch_sizes=bs[:1],
-                                  epochs=epochs),
-             lambda rows: rows[-1] if rows else "-")
-    _section("fig6_scaling", scaling.run,
-             lambda rows: rows[-1] if rows else "-")
-    _section("fig7_memory", memory.run,
-             lambda rows: rows[-1] if rows else "-")
-    _section("table3_energy", energy.run,
-             lambda rows: next((r for r in rows if r.startswith("total")),
-                               "-"))
-    _section("fig9_convergence", convergence.run,
-             lambda rows: rows[-1] if rows else "-")
-    _section("beyond_embedding_cache", embedding_cache.run,
-             lambda rows: rows[-1] if rows else "-")
     campaign_box = {}
 
     def _campaign():
@@ -141,28 +124,59 @@ def main() -> None:
         return rows
 
     _section("paper_campaign", _campaign,
-             lambda rows: rows[-1] if rows else "-")
+             lambda rows: rows[-1] if rows else "-", failed)
 
     def _device_epoch():
         from repro.eval.cells import CellResult
 
+        # the campaign's device cells, never a fresh child: this process
+        # has run JAX by now and may hold the accelerator
         rep = campaign_box.get("report")
-        reuse = None
-        if rep is not None:
-            dev = [CellResult.from_dict(d) for d in rep["cells"]
-                   if d["spec"]["backend"] == "device"]
-            if dev and all(d.spec["epochs"] == epochs + 1 for d in dev):
-                reuse = dev
-        return device_epoch.run(epochs=epochs + 1, results=reuse)
+        dev = [CellResult.from_dict(d) for d in (rep or {}).get("cells", [])
+               if d["spec"]["backend"] == "device"]
+        if not dev:
+            raise RuntimeError("paper_campaign produced no device cells")
+        return device_epoch.run(results=dev)
 
     _section("device_epoch", _device_epoch,
-             lambda rows: rows[-1] if rows else "-")
+             lambda rows: rows[-1] if rows else "-", failed)
+    _section("table2_speedup",
+             lambda: speedup.run(datasets=ds, batch_sizes=bs,
+                                 epochs=epochs),
+             lambda rows: rows[-1] if rows else "-", failed)
+    _section("fig3_access_distribution", access_dist.run,
+             lambda rows: next((r for r in rows if "once" in r), "-"),
+             failed)
+    _section("fig4_comm_volume",
+             lambda: comm_volume.run(datasets=ds, batch_sizes=bs,
+                                     epochs=epochs),
+             lambda rows: rows[-1] if rows else "-", failed)
+    _section("fig5_cache_sweep",
+             lambda: cache_sweep.run(batch_sizes=bs[:1]),
+             lambda rows: rows[-1] if rows else "-", failed)
+    # raises (-> section FAILED) on a broken intra+inter byte-sum
+    # identity or a DCN bias that raises cross-host traffic
+    _section("topology",
+             lambda: topology.run(datasets=ds, batch_sizes=bs[:1],
+                                  epochs=epochs),
+             lambda rows: rows[-1] if rows else "-", failed)
+    _section("fig6_scaling", scaling.run,
+             lambda rows: rows[-1] if rows else "-", failed)
+    _section("fig7_memory", memory.run,
+             lambda rows: rows[-1] if rows else "-", failed)
+    _section("table3_energy", energy.run,
+             lambda rows: next((r for r in rows if r.startswith("total")),
+                               "-"), failed)
+    _section("fig9_convergence", convergence.run,
+             lambda rows: rows[-1] if rows else "-", failed)
+    _section("beyond_embedding_cache", embedding_cache.run,
+             lambda rows: rows[-1] if rows else "-", failed)
     _section("assemble_collation", assemble.run,
-             lambda rows: rows[-1] if rows else "-")
+             lambda rows: rows[-1] if rows else "-", failed)
     # raises (-> section FAILED -> CI bench job fails) on any
     # batched-vs-loop schedule parity mismatch, campaign-style
     _section("schedule_build", schedule_build.run,
-             lambda rows: rows[-1] if rows else "-")
+             lambda rows: rows[-1] if rows else "-", failed)
 
     def _fault_recovery():
         """Seeded chaos sweep (DESIGN.md §10): every injected fault
@@ -186,7 +200,7 @@ def main() -> None:
         return rows
 
     _section("fault_recovery", _fault_recovery,
-             lambda rows: rows[-1] if rows else "-")
+             lambda rows: rows[-1] if rows else "-", failed)
 
     def _serve():
         """Online-serving latency lanes (DESIGN.md §11): clean vs
@@ -198,7 +212,7 @@ def main() -> None:
         return serve_latency.run(requests=64 if args.full else 32)
 
     _section("serve_latency", _serve,
-             lambda rows: rows[-1] if rows else "-")
+             lambda rows: rows[-1] if rows else "-", failed)
     if not args.skip_roofline:
         from benchmarks import roofline
 
@@ -215,8 +229,12 @@ def main() -> None:
             return out
 
         _section("roofline", _roof,
-                 lambda rows: f"{len(rows) - 1}_combos")
+                 lambda rows: f"{len(rows) - 1}_combos", failed)
+
+    if failed:
+        print(f"FAILED sections: {','.join(failed)}")
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

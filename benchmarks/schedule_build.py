@@ -20,9 +20,8 @@ Three sections; the first two at a 64- and a 256-worker partition point:
 
 Device-compiler caveat (recorded honestly, PR 5 precedent): on a
 single-CPU host the device columns lose to numpy -- XLA's comparison
-sort vs numpy's radix sort on one core. The port's case is the TPU
-radix path (``repro.kernels.seg_sort``) + staging-thread overlap, not
-single-core CPU throughput.
+sort vs numpy's radix sort on one core. The port's case is the
+accelerator + staging-thread overlap, not single-core CPU throughput.
 
 Per-worker train mass follows the assemble-bench convention of
 paper-proportioned shapes: ogbn-papers100M has ~1.2 M train nodes, so a

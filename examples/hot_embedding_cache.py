@@ -11,6 +11,7 @@ against a direct numpy gather.
 import numpy as np
 
 from repro.configs import get_arch
+from repro.dist.mesh import host_device_flags
 from repro.data.pipeline import zipf_tokens, enumerate_token_accesses
 from repro.graph.sampler import rng_from
 from repro.models.transformer.embedding import HotEmbeddingSim
@@ -78,7 +79,7 @@ np.testing.assert_allclose(np.asarray(out), np.stack(want), rtol=1e-6)
 print("   device embedding lookup == direct gather OK")
 """
 env = dict(os.environ)
-env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+env["XLA_FLAGS"] = host_device_flags(4, env.get("XLA_FLAGS", ""))
 env.setdefault("PYTHONPATH", "src")
 r = subprocess.run([sys.executable, "-c", code], env=env,
                    capture_output=True, text=True)

@@ -32,7 +32,7 @@ TRACE_WRAPPERS = {
     "jax.lax.scan", "jax.lax.while_loop", "jax.lax.fori_loop",
     "jax.lax.cond", "jax.lax.switch", "jax.lax.map",
     "jax.lax.associative_scan", "jax.lax.custom_root",
-    "jax.experimental.shard_map.shard_map",
+    "jax.shard_map",
     "jax.experimental.pallas.pallas_call",
 }
 

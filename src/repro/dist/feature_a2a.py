@@ -36,7 +36,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.kernels.cache_lookup.ops import cache_lookup
 
@@ -345,7 +345,7 @@ def pull_features(mesh, table: jnp.ndarray, send_ids: jnp.ndarray,
     return shard_map(
         body, mesh=mesh,
         in_specs=(P("data"), P("data"), P("data"), P("data"), P("data")),
-        out_specs=P("data"), check_rep=False,
+        out_specs=P("data"), check_vma=False,
     )(table, send_ids, send_pos, send_mask, offsets)
 
 

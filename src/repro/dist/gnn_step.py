@@ -40,7 +40,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
+from jax.flatten_util import ravel_pytree
 
 from repro.core.schedule import EpochSchedule, collate
 from repro.graph.partition import PartitionedGraph
@@ -519,14 +520,21 @@ def _pmean_train_step(cfg: GNNConfig, opt, params, opt_state, feats, x,
     pmean over the full worker ``axis`` (``"data"`` flat, ``("dcn",
     "data")`` hierarchical -- the same all-group AllReduce, so params
     stay replicated and curves stay bit-comparable), optimizer update.
-    -> (params, opt_state, loss, acc)."""
+    -> (params, opt_state, loss, acc).
+
+    The AllReduce runs on ONE flat buffer. With per-leaf all-reduces the
+    two epoch programs drifted apart by rounding on four TPU chips; with
+    the flat buffer they agree bit for bit. The likely cause, not
+    confirmed from the HLO, is that XLA combined the per-leaf reductions
+    differently in the two programs."""
 
     def lf(p):
         return loss_fn(cfg, p, feats, x["edge_src"], x["edge_dst"],
                        x["edge_mask"], x["labels"], x["seed_mask"])
 
     (loss, acc), grads = jax.value_and_grad(lf, has_aux=True)(params)
-    grads, loss, acc = jax.lax.pmean((grads, loss, acc), axis)
+    flat, unravel = ravel_pytree((grads, loss, acc))
+    grads, loss, acc = unravel(jax.lax.pmean(flat, axis))
     p2, o2 = opt.update(grads, opt_state, params)
     return p2, o2, loss, acc
 
@@ -610,7 +618,7 @@ def make_pipelined_epoch(cfg: GNNConfig, opt, mesh, m_max: int,
             device_epoch, mesh=mesh,
             in_specs=(P(), P(), P(ax), P(ax), P(ax),
                       P(ax), P(None, ax)),
-            out_specs=(P(), P(), P(), P()), check_rep=False,
+            out_specs=(P(), P(), P(), P()), check_vma=False,
         )(params, opt_state, table, offsets, cache_ids, cache_feats,
           batches)
 
@@ -677,7 +685,7 @@ def make_ondemand_epoch(cfg: GNNConfig, opt, mesh, m_max: int,
         return shard_map(
             device_epoch, mesh=mesh,
             in_specs=(P(), P(), P(ax), P(ax), P(None, ax)),
-            out_specs=(P(), P(), P(), P()), check_rep=False,
+            out_specs=(P(), P(), P(), P()), check_vma=False,
         )(params, opt_state, table, offsets, batches)
 
     return epoch_fn

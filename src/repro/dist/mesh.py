@@ -17,11 +17,26 @@ from __future__ import annotations
 from typing import Optional, Sequence, Tuple, Union
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> jax.sharding.Mesh:
-    """Build a device mesh, e.g. ``make_mesh((4,), ("data",))``."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """Build a device mesh, e.g. ``make_mesh((4,), ("data",))``.
+
+    Axes are ``Auto``: every program here partitions through explicit
+    ``shard_map`` specs, and with ``jax.make_mesh``'s ``Explicit`` default
+    a jitted epoch's outputs carry mesh-typed avals that its fresh inputs
+    lack, so the second epoch would trace and compile again."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
+
+
+def host_device_flags(n: int, flags: str = "") -> str:
+    """``flags`` (an ``XLA_FLAGS`` value) plus a request for ``n`` XLA CPU
+    devices; flags the caller already set survive. The device count locks
+    at the first JAX backend use, so set it before that."""
+    return " ".join(filter(None, (
+        flags, f"--xla_force_host_platform_device_count={n}")))
 
 
 def dp_axes(mesh) -> Optional[Union[str, Tuple[str, ...]]]:

@@ -41,6 +41,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core.schedule import WorkerSchedule, merge_pad_bounds
 from repro.fault.inject import TransientFault, fault_point
@@ -395,6 +396,11 @@ class _DeviceRunnerBase:
             params = init_params(self.cfg, jax.random.key(self.seed))
         if opt_state is None:
             opt_state = self.opt.init(params)
+        # place the carried state where the epoch program returns it
+        # (replicated over the mesh), so epoch 0 and every later epoch
+        # present the same input shardings: one trace, one compile
+        replicated = NamedSharding(self.mesh, PartitionSpec())
+        params, opt_state = jax.device_put((params, opt_state), replicated)
         table = jnp.asarray(self.dv.table)
         offsets = jnp.asarray(self.dv.offsets)
         reports: List[DeviceEpochReport] = []

@@ -28,6 +28,7 @@ import os
 import sys
 from typing import Callable, List, Optional
 
+from repro.compile_cache import enable_compile_cache
 from repro.eval.spec import CampaignSpec, fast_grid, fault_grid, full_grid
 from repro.eval.cells import (CellResult, run_host_cell,
                               run_device_cells, device_child_main)
@@ -41,6 +42,24 @@ DEFAULT_OUT = os.path.join(ROOT, "artifacts", "BENCH_paper.json")
 FAULT_OUT = os.path.join(ROOT, "artifacts", "BENCH_fault.json")
 
 
+def _device_cells_first(spec: CampaignSpec, include_device: bool,
+                        log: Callable[[str], None]) -> List[CellResult]:
+    """Run the spec's device cells in child processes while this process
+    has not yet touched JAX (one process per chip)."""
+    dev = spec.device_cells() if include_device else []
+    if not dev:
+        return []
+    log(f"[cell] {len(dev)} device cell(s) via subprocess ...")
+    cells = run_device_cells(dev)
+    for c in cells:
+        log(f"[cell] {c.spec['backend']}/{c.spec['system']}/"
+            f"{c.spec.get('fault_profile', 'none')} done: "
+            f"step={c.step_time_ms:.2f}ms lanes={c.rpc_count} "
+            f"fires={c.fault_events} degraded={c.degraded_epochs} "
+            f"retries={c.stage_retries}")
+    return cells
+
+
 def run_campaign(spec: CampaignSpec, include_device: bool = True,
                  out_path: Optional[str] = None,
                  log: Callable[[str], None] = lambda s: None,
@@ -50,20 +69,15 @@ def run_campaign(spec: CampaignSpec, include_device: bool = True,
     artifact. ``mutate_cells`` is the injection hook: it edits the
     measured cells before verification (tests + ``--inject-miscount``
     use it to prove a perturbed counter is caught)."""
-    cells: List[CellResult] = []
+    # device cells FIRST: their child processes need the accelerator,
+    # which the parent would hold once its host cells have run JAX
+    cells = _device_cells_first(spec, include_device, log)
     for c in spec.host_cells():
         log(f"[cell] {c.label()} ...")
         cells.append(run_host_cell(c))
         log(f"[cell] {c.label()} done: "
             f"step={cells[-1].step_time_ms:.2f}ms "
             f"rpc={cells[-1].rpc_count}")
-    dev = spec.device_cells()
-    if dev and include_device:
-        log(f"[cell] {len(dev)} device cell(s) via subprocess ...")
-        cells.extend(run_device_cells(dev))
-        for c in cells[-len(dev):]:
-            log(f"[cell] {c.spec['backend']}/{c.spec['system']} done: "
-                f"step={c.step_time_ms:.2f}ms lanes={c.rpc_count}")
     if mutate_cells is not None:
         mutate_cells(cells)
     checks = verify_cells(cells)
@@ -83,21 +97,12 @@ def run_fault_campaign(include_device: bool = True,
     to (a) fire and (b) recover bit-exactly against its clean twin.
     Artifact: ``artifacts/BENCH_fault.json``."""
     spec = fault_grid()
-    cells: List[CellResult] = []
+    cells = _device_cells_first(spec, include_device, log)
     for c in spec.host_cells():
         log(f"[cell] {c.label()} ...")
         cells.append(run_host_cell(c))
         log(f"[cell] {c.label()} done: fires={cells[-1].fault_events} "
             f"degraded={cells[-1].degraded_epochs}")
-    dev = spec.device_cells()
-    if dev and include_device:
-        log(f"[cell] {len(dev)} device cell(s) via subprocess ...")
-        cells.extend(run_device_cells(dev))
-        for c in cells[-len(dev):]:
-            log(f"[cell] {c.spec['backend']}/"
-                f"{c.spec.get('fault_profile', 'none')} done: "
-                f"fires={c.fault_events} degraded={c.degraded_epochs} "
-                f"retries={c.stage_retries}")
     checks = verify_cells(cells) + verify_fault_pairs(cells)
     report = build_fault_report(spec.name, cells, checks)
     if out_path:
@@ -185,6 +190,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     if args.device_child:
+        enable_compile_cache()
         device_child_main(*args.device_child)
         return 0
 

@@ -292,6 +292,8 @@ def run_device_cells(specs: Sequence[CellSpec],
     """Run device cells in child processes (one per distinct worker
     count), each pinned to that many emulated host devices. Results come
     back through a JSON file, never stdout (jax logs pollute it)."""
+    from repro.dist.mesh import host_device_flags
+
     by_P: Dict[int, List[CellSpec]] = {}
     for s in specs:
         if s.backend != "device":
@@ -306,8 +308,8 @@ def run_device_cells(specs: Sequence[CellSpec],
             with open(spec_path, "w") as f:
                 json.dump([s.to_dict() for s in group], f)
             env = dict(os.environ)
-            env["XLA_FLAGS"] = \
-                f"--xla_force_host_platform_device_count={P_}"
+            env["XLA_FLAGS"] = host_device_flags(P_,
+                                                 env.get("XLA_FLAGS", ""))
             env["PYTHONPATH"] = (os.path.join(ROOT, "src") + os.pathsep +
                                  env.get("PYTHONPATH", ""))
             r = subprocess.run(
@@ -372,29 +374,36 @@ def _build_device_scenario(spec: CellSpec) -> dict:
             "dv": DeviceView.build(pg)}
 
 
-def _run_device_cell(spec: CellSpec, sc: dict) -> CellResult:
+def build_device_runner(spec: CellSpec, sc: dict):
+    """The SPMD runner one device cell drives over scenario ``sc``
+    (``_build_device_scenario``); ``chip_smoke.py`` drives the same."""
     from repro.models import GNNConfig
     from repro.train import AdamW
     from repro.dist import DeviceRapidGNNRunner, DeviceBaselineRunner
-    from repro.fault import active_plan, plan_from_profile
 
-    g, schedules = sc["g"], sc["schedules"]
+    g = sc["g"]
     cfg = GNNConfig(kind="sage", in_dim=g.feat_dim,
                     hidden_dim=spec.hidden, num_classes=g.num_classes,
                     num_layers=len(spec.fanouts))
     topo = spec.topology_obj()
     cls = DeviceRapidGNNRunner if spec.is_rapid else DeviceBaselineRunner
-    runner = cls(schedules, sc["dv"], cfg, AdamW(lr=3e-3),
-                 topo.make_mesh(), spec.batch_size, g.labels,
-                 seed=spec.seed, stage_deadline_s=spec.stage_deadline_s,
-                 topology=topo)
+    return cls(sc["schedules"], sc["dv"], cfg, AdamW(lr=3e-3),
+               topo.make_mesh(), spec.batch_size, g.labels,
+               seed=spec.seed, stage_deadline_s=spec.stage_deadline_s,
+               topology=topo)
+
+
+def _run_device_cell(spec: CellSpec, sc: dict) -> CellResult:
+    from repro.fault import active_plan, plan_from_profile
+
+    runner = build_device_runner(spec, sc)
     plan = (plan_from_profile(spec.fault_profile, seed=spec.fault_seed)
             if spec.fault_profile != "none" else None)
     with active_plan(plan):
         reports = runner.run()
-    return device_cell_result(spec, g, schedules, runner, reports,
-                              fault_events=plan.total_fires() if plan
-                              else 0)
+    return device_cell_result(spec, sc["g"], sc["schedules"], runner,
+                              reports, fault_events=plan.total_fires()
+                              if plan else 0)
 
 
 def device_cell_result(spec: CellSpec, g, schedules, runner,

@@ -3,7 +3,7 @@
 Ports the sort-bound middle of ``KHopSampler.sample_epoch_batched`` --
 the composite-key segment-unique, frontier membership, new-source
 extraction and local-index resolution -- onto the accelerator as JAX
-ops (``repro.kernels.seg_sort`` for the key sort, scatter/gather tables
+ops (a stable ``jax.lax.sort`` for the key sort, scatter/gather tables
 for the unique-inverse), plus device remote-frequency counting and
 hot-set ordering. The result is BIT-IDENTICAL to the numpy compiler:
 every derived quantity is a deterministic function of the sorted unique
@@ -31,7 +31,7 @@ epochs re-use each other's compiled steps.
 from __future__ import annotations
 
 from functools import partial
-from typing import List, Tuple
+from typing import List, Optional, Tuple
 
 import numpy as np
 import jax
@@ -39,7 +39,6 @@ import jax.numpy as jnp
 
 from repro.graph.sampler import (FlatEpoch, KEY_INT32_MAX_SLOTS,
                                  KHopSampler, _starts, rng_from)
-from repro.kernels.seg_sort import seg_sort
 
 #: int32 padding sentinel: sorts after every real composite key (key
 #: spaces are gated below 2^31, so max real key <= 2^31 - 2).
@@ -64,16 +63,26 @@ def _pad_i32(x: np.ndarray, n_pad: int, fill: int = SENT) -> jnp.ndarray:
     return jnp.asarray(out)
 
 
+def stable_sort(keys: jax.Array, payload: Optional[jax.Array] = None
+                ) -> Tuple[jax.Array, Optional[jax.Array]]:
+    """Sort int32 composite ``(batch, id)`` keys ascending, permuting
+    ``payload`` along (stable). One global sort acts per batch, since
+    keys never cross segment boundaries; SENT-padded tails sort last.
+    Returns ``(sorted_keys, sorted_payload_or_None)``."""
+    if payload is None:
+        return jax.lax.sort(keys, is_stable=True), None
+    ks, ps = jax.lax.sort((keys, payload), num_keys=1, is_stable=True)
+    return ks, ps
+
+
 # ---------------------------------------------------------------------------
 # the per-layer device step
 # ---------------------------------------------------------------------------
 
-@partial(jax.jit, static_argnames=("nb", "span", "use_table",
-                                   "sort_backend", "interpret"))
+@partial(jax.jit, static_argnames=("nb", "span", "use_table"))
 def _frontier_step(cand_key: jax.Array, cur_key: jax.Array,
                    cur_within: jax.Array, counts: jax.Array, *,
-                   nb: int, span: int, use_table: bool,
-                   sort_backend: str, interpret: bool
+                   nb: int, span: int, use_table: bool
                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One sampler layer's segment-unique on device.
 
@@ -89,12 +98,10 @@ def _frontier_step(cand_key: jax.Array, cur_key: jax.Array,
     """
     n_pad = cand_key.shape[0]
     ks = nb * span
-    num_bits = max(int(ks - 1).bit_length(), 1)
 
     # segment-unique: ONE global sort acts per batch (composite keys
     # never cross segment boundaries), then head flags + compaction
-    sk, _ = seg_sort(cand_key, num_bits=num_bits, backend=sort_backend,
-                     interpret=interpret)
+    sk, _ = stable_sort(cand_key)
     valid = sk != SENT
     head = valid & jnp.concatenate(
         [jnp.ones((1,), bool), sk[1:] != sk[:-1]])
@@ -111,8 +118,7 @@ def _frontier_step(cand_key: jax.Array, cur_key: jax.Array,
             cur_within, mode="drop")          # SENT pads drop (>= ks)
         old_within = cur_tbl[jnp.minimum(uk, ks - 1)]
     else:
-        cks, cw = seg_sort(cur_key, cur_within, num_bits=num_bits,
-                           backend=sort_backend, interpret=interpret)
+        cks, cw = stable_sort(cur_key, cur_within)
         pos = jnp.minimum(jnp.searchsorted(cks, uk),
                           cks.shape[0] - 1).astype(jnp.int32)
         old_within = jnp.where(cks[pos] == uk, cw[pos], -1)
@@ -151,9 +157,8 @@ def _frontier_step(cand_key: jax.Array, cur_key: jax.Array,
 # ---------------------------------------------------------------------------
 
 def sample_epoch_batched_device(sampler: KHopSampler, s0: int, worker: int,
-                                epoch: int, train_nodes: np.ndarray, *,
-                                sort_backend: str = "auto",
-                                interpret: bool = False) -> FlatEpoch:
+                                epoch: int, train_nodes: np.ndarray
+                                ) -> FlatEpoch:
     """Whole-epoch compile with the per-layer segment-unique on device;
     bit-identical to ``sample_epoch_batched`` (the differential suite
     pins it array-for-array). Falls back to the numpy compiler for
@@ -220,8 +225,7 @@ def sample_epoch_batched_device(sampler: KHopSampler, s0: int, worker: int,
             _pad_i32(cur_key, c_pad),
             _pad_i32(within.astype(np.int32), c_pad, fill=0),
             jnp.asarray(counts.astype(np.int32)),
-            nb=nb, span=span, use_table=use_table,
-            sort_backend=sort_backend, interpret=interpret)
+            nb=nb, span=span, use_table=use_table)
 
         src_idx = np.asarray(d_src)[:n_edges].astype(np.int32,
                                                      copy=False)
@@ -262,13 +266,10 @@ def sample_epoch_batched_device(sampler: KHopSampler, s0: int, worker: int,
 # device remote-frequency counting + hot-set ordering
 # ---------------------------------------------------------------------------
 
-@partial(jax.jit, static_argnames=("span", "sort_backend", "interpret"))
-def _freq_step(r: jax.Array, *, span: int, sort_backend: str,
-               interpret: bool):
+@jax.jit
+def _freq_step(r: jax.Array):
     m_pad = r.shape[0]
-    num_bits = max(int(span - 1).bit_length(), 1)
-    sk, _ = seg_sort(r, num_bits=num_bits, backend=sort_backend,
-                     interpret=interpret)
+    sk, _ = stable_sort(r)
     valid = sk != SENT
     head = valid & jnp.concatenate(
         [jnp.ones((1,), bool), sk[1:] != sk[:-1]])
@@ -295,9 +296,7 @@ def _hot_order(ids: jax.Array, freq: jax.Array) -> jax.Array:
     return sid
 
 
-def device_remote_freq(remote: np.ndarray, span: int, *,
-                       sort_backend: str = "auto",
-                       interpret: bool = False
+def device_remote_freq(remote: np.ndarray, span: int
                        ) -> Tuple[np.ndarray, np.ndarray]:
     """``np.unique(remote, return_counts=True)`` as device ops (sort +
     run-length compaction). ``remote`` is the flat stream of remote
@@ -308,9 +307,7 @@ def device_remote_freq(remote: np.ndarray, span: int, *,
                      if remote.size else (np.zeros(0, np.int64),) * 2)
         return ids.astype(np.int64), np.asarray(freq, np.int64)
     m_pad = _bucket(remote.size)
-    uk, freq, nu = _freq_step(_pad_i32(remote.astype(np.int64), m_pad),
-                              span=span, sort_backend=sort_backend,
-                              interpret=interpret)
+    uk, freq, nu = _freq_step(_pad_i32(remote.astype(np.int64), m_pad))
     k = int(nu)
     return (np.asarray(uk)[:k].astype(np.int64),
             np.asarray(freq)[:k].astype(np.int64))

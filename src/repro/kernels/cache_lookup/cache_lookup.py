@@ -4,10 +4,14 @@ Two fused stages (DESIGN.md §3 kernels):
 
   1. ``search``  -- positions of queries in the SORTED cache-id vector.
      TPU adaptation: instead of a per-lane binary search (serial, gather-
-     heavy), each (Tq x Tc) tile computes comparison-mask partial sums on
-     the VPU:  pos(q) = #&#123;ids < q&#125;,  hit(q) = any(ids == q).  The cache-id
-     vector streams through VMEM in Tc-sized tiles, so n_hot is unbounded
-     by VMEM and every op is dense vector work (MXU/VPU aligned).
+     heavy), each (Tc x Tq) tile computes comparison-mask partial sums on
+     the VPU:  pos(q) = #&#123;ids < q&#125;,  hit(q) = any(ids == q).  Queries
+     ride the lanes as a (1, m) row and cache ids the sublanes as an
+     (n_hot, 1) column, so every block is 2-D and (8, 128)-aligned (the
+     TPU compiler refuses 1-D blocks that do not match the XLA tiling
+     of a 1-D array) and the sublane reduction lands lane-dense. The
+     cache-id column streams through VMEM in Tc-sized tiles, so n_hot is
+     unbounded by VMEM and every op is dense vector work.
   2. ``merge_gather`` -- one cached feature row per grid step, selected by
      a scalar-prefetched BlockSpec index map, merged over the pre-filled
      base buffer (hits win, misses keep the SyncPull value).
@@ -23,8 +27,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-DEFAULT_TQ = 256
-DEFAULT_TC = 1024
+DEFAULT_TQ = 512           # queries per tile (lanes, multiple of 128)
+DEFAULT_TC = 512           # cache ids per tile (sublanes, multiple of 8)
 
 #: int32 cache sentinel: compares >= every real device id, so padding the
 #: cache-id vector with it never perturbs ``pos = #{ids < q}`` or ``hit``.
@@ -46,6 +50,10 @@ def pad_to(x: jax.Array, mult: int, axis: int, value) -> jax.Array:
     return jnp.pad(x, width, constant_values=value)
 
 
+def _round_up(n: int, mult: int) -> int:
+    return -(-n // mult) * mult
+
+
 def _search_kernel(q_ref, ids_ref, pos_ref, hit_ref):
     j = pl.program_id(1)
 
@@ -54,12 +62,13 @@ def _search_kernel(q_ref, ids_ref, pos_ref, hit_ref):
         pos_ref[...] = jnp.zeros_like(pos_ref)
         hit_ref[...] = jnp.zeros_like(hit_ref)
 
-    q = q_ref[...]                     # (Tq,)
-    ids = ids_ref[...]                 # (Tc,)
-    lt = (ids[None, :] < q[:, None])
-    eq = (ids[None, :] == q[:, None])
-    pos_ref[...] += lt.sum(axis=1).astype(jnp.int32)
-    hit_ref[...] |= eq.any(axis=1)
+    q = q_ref[...]                     # (1, Tq)
+    ids = ids_ref[...]                 # (Tc, 1)
+    pos_ref[...] += jnp.sum((ids < q).astype(jnp.int32), axis=0,
+                            keepdims=True)
+    hit_ref[...] = jnp.maximum(
+        hit_ref[...], jnp.max((ids == q).astype(jnp.int32), axis=0,
+                              keepdims=True))
 
 
 def search(cache_ids: jax.Array, query: jax.Array, tq: int = DEFAULT_TQ,
@@ -79,25 +88,24 @@ def search(cache_ids: jax.Array, query: jax.Array, tq: int = DEFAULT_TQ,
         return (jnp.zeros((0,), jnp.int32), jnp.zeros((0,), jnp.bool_))
     if cache_ids.shape[0] == 0:
         cache_ids = jnp.full((1,), SENTINEL, jnp.int32)
-    tq = min(tq, m)
-    tc = min(tc, cache_ids.shape[0])
+    tq = min(tq, _round_up(m, 128))
+    tc = min(tc, _round_up(cache_ids.shape[0], 8))
     query = pad_to(query, tq, 0, -1)
     cache_ids = pad_to(cache_ids, tc, 0, SENTINEL)
     mp = query.shape[0]
     n_hot = cache_ids.shape[0]
-    grid = (mp // tq, n_hot // tc)
     pos, hit = pl.pallas_call(
         _search_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((tq,), lambda i, j: (i,)),
-                  pl.BlockSpec((tc,), lambda i, j: (j,))],
-        out_specs=[pl.BlockSpec((tq,), lambda i, j: (i,)),
-                   pl.BlockSpec((tq,), lambda i, j: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((mp,), jnp.int32),
-                   jax.ShapeDtypeStruct((mp,), jnp.bool_)],
+        grid=(mp // tq, n_hot // tc),
+        in_specs=[pl.BlockSpec((1, tq), lambda i, j: (0, i)),
+                  pl.BlockSpec((tc, 1), lambda i, j: (j, 0))],
+        out_specs=[pl.BlockSpec((1, tq), lambda i, j: (0, i)),
+                   pl.BlockSpec((1, tq), lambda i, j: (0, i))],
+        out_shape=[jax.ShapeDtypeStruct((1, mp), jnp.int32),
+                   jax.ShapeDtypeStruct((1, mp), jnp.int32)],
         interpret=interpret,
-    )(query, cache_ids)
-    return pos[:m], hit[:m] & (query[:m] != SENTINEL)
+    )(query.reshape(1, mp), cache_ids.reshape(n_hot, 1))
+    return pos[0, :m], (hit[0, :m] > 0) & (query[:m] != SENTINEL)
 
 
 def _merge_kernel(pos, hit, feats_ref, base_ref, o_ref):

@@ -14,13 +14,14 @@ import jax.numpy as jnp
 from repro.kernels.cache_lookup.cache_lookup import cache_lookup as _kernel
 from repro.kernels.cache_lookup.ref import cache_lookup_ref
 
-INT32_SENTINEL = jnp.int32(2 ** 31 - 1)
+#: a plain int: importing this module must not initialise a JAX backend
+INT32_SENTINEL = 2 ** 31 - 1
 
 
 def to_device_ids(ids64) -> jax.Array:
     """Clamp the int64 CACHE_PAD sentinel into int32 space."""
-    return jnp.where(ids64 >= INT32_SENTINEL.astype(jnp.int64),
-                     INT32_SENTINEL.astype(jnp.int64), ids64).astype(jnp.int32)
+    return jnp.where(ids64 >= INT32_SENTINEL, INT32_SENTINEL,
+                     ids64).astype(jnp.int32)
 
 
 @partial(jax.jit, static_argnames=("use_kernel", "interpret"))

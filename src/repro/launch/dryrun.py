@@ -11,9 +11,6 @@ bytes), per-collective byte volumes parsed from the post-SPMD HLO, and
 compile wall-time. Default sweeps the full 10 x 4 matrix.
 """
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax import (device count locks on first init).
-
 import argparse
 import json
 import re
@@ -23,6 +20,7 @@ import traceback
 import jax
 
 from repro.configs import ARCH_NAMES, INPUT_SHAPES
+from repro.dist.mesh import host_device_flags
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import make_dryrun_spec
 
@@ -32,6 +30,13 @@ _DTYPE_BYTES = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2, "s64": 8,
                 "s32": 4, "u32": 4, "s16": 2, "u16": 2, "s8": 1, "u8": 1,
                 "pred": 1, "f8e4m3fn": 1, "f8e5m2": 1}
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
+
+
+def emulate_host_devices(n: int = 512) -> None:
+    """Ask XLA's CPU backend for ``n`` devices, keeping flags already set.
+    Call before the first JAX backend use: the device count locks then."""
+    os.environ["XLA_FLAGS"] = host_device_flags(
+        n, os.environ.get("XLA_FLAGS", ""))
 
 
 def _shape_bytes(dtype: str, dims: str) -> int:
@@ -186,6 +191,7 @@ def main() -> None:
                     help="comma list: seqshard,resident (EXPERIMENTS §Perf)")
     ap.add_argument("--out", default="artifacts/dryrun")
     args = ap.parse_args()
+    emulate_host_devices()
 
     archs = ARCH_NAMES if args.arch == "all" else [args.arch]
     shapes = list(INPUT_SHAPES) if args.shape == "all" else [args.shape]

@@ -9,8 +9,6 @@ transformer dry-run.
   PYTHONPATH=src python -m repro.launch.dryrun_gnn [--multi-pod]
 """
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 import argparse
 import json
 import time
@@ -22,7 +20,8 @@ import numpy as np
 from repro.models.gnn import GNNConfig
 from repro.train.optim import AdamW
 from repro.dist.gnn_step import make_ondemand_epoch, make_pipelined_epoch
-from repro.launch.dryrun import collective_bytes
+from repro.dist.mesh import make_mesh
+from repro.launch.dryrun import collective_bytes, emulate_host_devices
 
 
 def specs(P_, S, m_max, edge_max, B, n_per, d, n_hot, k_max, n_classes):
@@ -63,8 +62,9 @@ def main() -> None:
                          "ref elsewhere)")
     ap.add_argument("--out", default="artifacts/dryrun")
     args = ap.parse_args()
+    emulate_host_devices()
     P_ = 512 if args.multi_pod else 256
-    mesh = jax.make_mesh((P_,), ("data",))
+    mesh = make_mesh((P_,), ("data",))
 
     # paper-scale per-worker shapes: OGBN-Papers100M-like partition
     d, B, n_hot, k_max, m_max = 128, 1000, 32768, 4096, 60_000

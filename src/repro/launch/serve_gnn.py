@@ -17,6 +17,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.fault.inject import active_plan
 from repro.fault.plan import PROFILES, plan_from_profile
 from repro.graph import KHopSampler, load_dataset, partition_graph
@@ -46,6 +47,7 @@ def main() -> None:
                     choices=sorted(PROFILES),
                     help="run the stream under a named fault plan")
     args = ap.parse_args()
+    enable_compile_cache()
 
     g = load_dataset(args.dataset, seed=args.seed)
     pg = partition_graph(g, args.parts, "greedy")

@@ -21,6 +21,8 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
+
 
 def run_gnn(args) -> None:
     import jax
@@ -141,6 +143,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=42)
     ap.add_argument("--ckpt", default="")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.workload == "gnn":
         run_gnn(args)
     else:

@@ -110,7 +110,7 @@ def moe_apply(params: Dict[str, jax.Array], x: jnp.ndarray,
         return out.reshape(B, S, d)
 
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     tp = mesh.shape["model"]
     n_local = cfg.num_experts // tp
     dp = dp_spec if dp_spec is not None else tuple(

@@ -14,7 +14,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.kernels.flash_decode.ops import flash_decode
 from repro.kernels.flash_decode.ref import finalize

@@ -133,6 +133,9 @@ def check_device_runner():
     assert runner.trace_count == 1, \
         f"expected ONE XLA trace across {epochs} epochs, got " \
         f"{runner.trace_count}"
+    # ... and one executable: epoch 0's inputs are placed like the
+    # outputs later epochs are fed, so nothing compiles a second time
+    assert runner._fn._cache_size() == 1
     losses = np.concatenate([r.losses for r in reports])
     assert not np.isnan(losses).any()
     assert reports[-1].losses[-1] < reports[0].losses[0]

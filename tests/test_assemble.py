@@ -101,6 +101,20 @@ def test_assemble_awkward_shapes():
     np.testing.assert_array_equal(fused, staged)
 
 
+@pytest.mark.parametrize("rows", [7, 16])
+def test_assemble_select_pass_in_chunks(monkeypatch, rows):
+    """Past ``MAX_PREFETCH_ROWS`` query rows the select pass runs in
+    several calls (its code vector lives in SMEM); the joined output is
+    bit-equal to the reference."""
+    from repro.kernels.assemble import assemble as kernel
+
+    monkeypatch.setattr(kernel, "MAX_PREFETCH_ROWS", rows)
+    args = _case("mixed", np.random.default_rng(5), m=41)
+    ref = np.asarray(assemble_features(*args, backend="ref"))
+    fused = np.asarray(kernel.assemble(*args, interpret=True))
+    np.testing.assert_array_equal(fused, ref)
+
+
 def test_assemble_priority_local_over_cache():
     """A locally owned id that ALSO appears in the cache serves the
     shard row (priority local > C_s > pulled), matching the staged

@@ -4,8 +4,8 @@
 ``sample_epoch_batched`` compiler over arbitrary drawn graphs (zero-
 degree nodes, empty/tiny train sets), on BOTH lookup paths (dense table
 and searchsorted) and through both fallbacks (int64 key spaces, empty
-epochs). The seg_sort kernel must match ``jax.lax.sort`` including
-stability; device hot-set selection must reproduce ``select_hot_set``;
+epochs). The key sort must be stable (numpy's stable argsort order);
+device hot-set selection must reproduce ``select_hot_set``;
 the background ``SpillWriter`` must round-trip bit-exact and surface
 writer-thread failures; lazy schedules must rebuild bit-equal epochs.
 """
@@ -161,61 +161,37 @@ def test_device_hot_set_matches_host():
                                   select_hot_set(ids, freq, 64))
 
 
-# ---- seg_sort kernel parity (interpret mode; TPU lane in CI) -------------
+# ---- the schedule compiler's key sort: stable lax.sort -------------------
 
-def test_radix_sort_matches_ref():
-    from repro.kernels.seg_sort import seg_sort
-    from repro.kernels.seg_sort.ref import seg_sort_ref
-
+def _sort_case(name):
     rng = np.random.default_rng(7)
-    keys = rng.integers(0, 1 << 20, size=1024).astype(np.int32)
-    keys[1000:] = 2 ** 31 - 1       # sentinel pad tail
-    payload = np.arange(1024, dtype=np.int32)
-    rk, rp = seg_sort_ref(keys, payload)
-    gk, gp = seg_sort(keys, payload, num_bits=21, backend="radix",
-                      interpret=True)
-    np.testing.assert_array_equal(np.asarray(gk), np.asarray(rk))
-    np.testing.assert_array_equal(np.asarray(gp), np.asarray(rp))
+    if name == "sentinel_tail":
+        keys = rng.integers(0, 1 << 20, size=1024).astype(np.int32)
+        keys[1000:] = dsm.SENT
+    elif name == "duplicates":
+        keys = rng.integers(0, 7, size=256).astype(np.int32)
+    elif name == "keys_only":
+        keys = np.array([5, 3, 5, 1], np.int32)
+    else:
+        keys = np.zeros(0, np.int32)
+    payload = (None if name == "keys_only"
+               else np.arange(keys.shape[0], dtype=np.int32))
+    return keys, payload
 
 
-def test_radix_sort_stability_under_duplicates():
-    from repro.kernels.seg_sort import seg_sort
-
-    rng = np.random.default_rng(8)
-    keys = rng.integers(0, 7, size=256).astype(np.int32)
-    payload = np.arange(256, dtype=np.int32)
-    gk, gp = seg_sort(keys, payload, num_bits=3, backend="radix",
-                      interpret=True)
+@pytest.mark.parametrize("name", ["sentinel_tail", "duplicates",
+                                  "keys_only", "empty"])
+def test_stable_sort_matches_numpy_stable(name):
+    """Equal keys keep input order, so the payload is permuted exactly
+    as numpy's stable argsort permutes it."""
+    keys, payload = _sort_case(name)
+    gk, gp = dsm.stable_sort(keys, payload)
     order = np.argsort(keys, kind="stable")
     np.testing.assert_array_equal(np.asarray(gk), keys[order])
-    np.testing.assert_array_equal(np.asarray(gp), payload[order])
-
-
-def test_seg_sort_backend_resolution():
-    import jax
-    from repro.kernels.seg_sort import resolve_backend
-    from repro.kernels.seg_sort.seg_sort import MAX_VMEM_N
-
-    with pytest.raises(ValueError):
-        resolve_backend("bogus")
-    assert resolve_backend("ref") == "ref"
-    # radix honours the VMEM residency bound
-    assert resolve_backend("radix", MAX_VMEM_N) == "radix"
-    assert resolve_backend("radix", MAX_VMEM_N + 1) == "ref"
-    want = "radix" if jax.default_backend() == "tpu" else "ref"
-    assert resolve_backend("auto", 128) == want
-
-
-def test_seg_sort_keys_only_and_empty():
-    from repro.kernels.seg_sort import seg_sort
-
-    keys = np.array([5, 3, 5, 1], np.int32)
-    gk, gp = seg_sort(keys, num_bits=3, backend="radix", interpret=True)
-    np.testing.assert_array_equal(np.asarray(gk), [1, 3, 5, 5])
-    assert gp is None
-    ek, ep = seg_sort(np.zeros(0, np.int32), backend="radix",
-                      interpret=True)
-    assert np.asarray(ek).size == 0 and ep is None
+    if payload is None:
+        assert gp is None
+    else:
+        np.testing.assert_array_equal(np.asarray(gp), payload[order])
 
 
 # ---- SpillWriter: background npz writes ----------------------------------
