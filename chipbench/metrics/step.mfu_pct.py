@@ -1,0 +1,10 @@
+"""Model FLOP/s utilization of the window, %: the FLOPs the forward and
+backward passes require (``chipbench.counts``: the valid dst rows' two
+products per layer plus the mean aggregation's adds, backward twice
+forward) over the window's wall time, chips and the chip's peak."""
+
+
+def read(run):
+    w = run.window
+    return (100.0 * w["work"]["flops"]
+            / (w["wall_s"] * run.chips * run.peaks["flops_per_s"]))
