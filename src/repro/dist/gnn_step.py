@@ -514,6 +514,28 @@ def prefetch_stream(send: Dict[str, jnp.ndarray]) -> Dict[str, jnp.ndarray]:
     return out
 
 
+def _pull(tbl, send, base, m_max: int, hier: bool, axis):
+    """One step's residual-miss pull under the ``pull`` scope: the flat
+    ``all_to_all`` exchange, or the two-tier one on a hierarchical
+    topology. Shared by both epoch programs."""
+    with jax.named_scope("pull"):
+        if hier:
+            return pull_shard_two_tier(tbl, send, base, m_max,
+                                       world_axes=axis)
+        return pull_shard(tbl, send["send_ids"], send["send_pos"],
+                          send["send_mask"], base, m_max)
+
+
+def _assemble(tbl, base, cids32, cfeats, ids, pulled, backend: str,
+              interpret: bool):
+    """One step's feature assembly (``kernels/assemble``) under the
+    ``assemble`` scope. Shared by both epoch programs."""
+    with jax.named_scope("assemble"):
+        return assemble_features(tbl, base, cids32, cfeats,
+                                 to_device_ids(ids), pulled,
+                                 backend=backend, interpret=interpret)
+
+
 def _pmean_train_step(cfg: GNNConfig, opt, params, opt_state, feats, x,
                       axis="data"):
     """Shared scan-body tail for both epoch programs: batch loss/grad,
@@ -522,6 +544,10 @@ def _pmean_train_step(cfg: GNNConfig, opt, params, opt_state, feats, x,
     stay replicated and curves stay bit-comparable), optimizer update.
     -> (params, opt_state, loss, acc).
 
+    Scopes: the loss under ``forward`` (its gradient's ops then carry
+    ``transpose(jvp(forward))``), the all-reduce under
+    ``grad_allreduce``, the update under ``optimizer``.
+
     The AllReduce runs on ONE flat buffer. With per-leaf all-reduces the
     two epoch programs drifted apart by rounding on four TPU chips; with
     the flat buffer they agree bit for bit. The likely cause, not
@@ -529,13 +555,16 @@ def _pmean_train_step(cfg: GNNConfig, opt, params, opt_state, feats, x,
     differently in the two programs."""
 
     def lf(p):
-        return loss_fn(cfg, p, feats, x["edge_src"], x["edge_dst"],
-                       x["edge_mask"], x["labels"], x["seed_mask"])
+        with jax.named_scope("forward"):
+            return loss_fn(cfg, p, feats, x["edge_src"], x["edge_dst"],
+                           x["edge_mask"], x["labels"], x["seed_mask"])
 
     (loss, acc), grads = jax.value_and_grad(lf, has_aux=True)(params)
-    flat, unravel = ravel_pytree((grads, loss, acc))
-    grads, loss, acc = unravel(jax.lax.pmean(flat, axis))
-    p2, o2 = opt.update(grads, opt_state, params)
+    with jax.named_scope("grad_allreduce"):
+        flat, unravel = ravel_pytree((grads, loss, acc))
+        grads, loss, acc = unravel(jax.lax.pmean(flat, axis))
+    with jax.named_scope("optimizer"):
+        p2, o2 = opt.update(grads, opt_state, params)
     return p2, o2, loss, acc
 
 
@@ -570,27 +599,21 @@ def make_pipelined_epoch(cfg: GNNConfig, opt, mesh, m_max: int,
         def device_epoch(params, opt_state, tbl, offs, cids, cfeats, bt):
             tbl = tbl[0]                          # (n_per, d) my shard
             base = offs.reshape(-1)[0]
-            cids32 = to_device_ids(cids[0])       # (n_hot,) sorted int32
+            with jax.named_scope("assemble"):
+                cids32 = to_device_ids(cids[0])   # (n_hot,) sorted int32
             cfe = cfeats[0]
             bt = jax.tree.map(lambda a: a[:, 0], bt)   # drop worker dim
 
             def pull(send):
-                if hier:
-                    return pull_shard_two_tier(tbl, send, base, m_max,
-                                               world_axes=ax)
-                return pull_shard(tbl, send["send_ids"], send["send_pos"],
-                                  send["send_mask"], base, m_max)
-
-            def assemble(pulled, ids):
-                return assemble_features(
-                    tbl, base, cids32, cfe, to_device_ids(ids), pulled,
-                    backend=assemble_backend,
-                    interpret=assemble_interpret)
+                return _pull(tbl, send, base, m_max, hier, ax)
 
             send = {k: bt[k] for k in pull_keys}
             # prefetch stream: step i's body pulls step i+1's misses; the
             # wrapped final element is fully masked (its pull would be
             # discarded), so no real lanes ride the wasted wrap fetch
+            with jax.named_scope("pull"):
+                next_send = prefetch_stream(send)
+                first = jax.tree.map(lambda a: a[0], send)
             xs = {
                 "input_nodes": bt["input_nodes"],
                 "labels": bt["labels"],
@@ -598,14 +621,16 @@ def make_pipelined_epoch(cfg: GNNConfig, opt, mesh, m_max: int,
                 "edge_src": bt["edge_src"],
                 "edge_dst": bt["edge_dst"],
                 "edge_mask": bt["edge_mask"],
-                "next_send": prefetch_stream(send),
+                "next_send": next_send,
             }
-            pulled0 = pull(jax.tree.map(lambda a: a[0], send))
+            pulled0 = pull(first)
 
             def step(carry, x):
                 params, opt_state, pulled = carry
                 nxt = pull(x["next_send"])        # overlap: no dep on train
-                feats = assemble(pulled, x["input_nodes"])
+                feats = _assemble(tbl, base, cids32, cfe,
+                                  x["input_nodes"], pulled,
+                                  assemble_backend, assemble_interpret)
                 p2, o2, loss, acc = _pmean_train_step(
                     cfg, opt, params, opt_state, feats, x, axis=ax)
                 return (p2, o2, nxt), (loss, acc)
@@ -660,17 +685,10 @@ def make_ondemand_epoch(cfg: GNNConfig, opt, mesh, m_max: int,
                 params, opt_state = carry
                 # pull THIS step's remote rows: the train step below
                 # depends on it, so nothing overlaps (on-demand fetch)
-                if hier:
-                    pulled = pull_shard_two_tier(tbl, x, base, m_max,
-                                                 world_axes=ax)
-                else:
-                    pulled = pull_shard(tbl, x["send_ids"], x["send_pos"],
-                                        x["send_mask"], base, m_max)
-                feats = assemble_features(
-                    tbl, base, None, None,
-                    to_device_ids(x["input_nodes"]), pulled,
-                    backend=assemble_backend,
-                    interpret=assemble_interpret)
+                pulled = _pull(tbl, x, base, m_max, hier, ax)
+                feats = _assemble(tbl, base, None, None, x["input_nodes"],
+                                  pulled, assemble_backend,
+                                  assemble_interpret)
                 p2, o2, loss, acc = _pmean_train_step(
                     cfg, opt, params, opt_state, feats, x, axis=ax)
                 return (p2, o2), (loss, acc)

@@ -29,10 +29,20 @@ rapid-vs-baseline step time is measurable on the same mesh.
 ``assert_host_parity`` checks the device runner's per-epoch residual-miss
 lane counts against the host-sim ``RapidGNNRunner``'s ``cache_misses``
 batch-exact on the identical schedule (DESIGN.md §7).
+
+Host spans: the epoch loop and the staging thread mark their phases
+with ``jax.profiler.TraceAnnotation`` spans named ``rapidgnn.*`` (main
+thread: ``epoch.dispatch``, ``epoch.readback``, ``stage.wait``,
+``epoch.report``; every ``_stage`` call: ``stage`` with its children
+``stage.schedule``, ``stage.caches``, ``stage.collate``,
+``stage.to_device`` and ``stage.stack_caches``). They land on the
+profiler's clock beside the device ops when a trace is running, and
+cost a few microseconds each when none is.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from concurrent.futures import ThreadPoolExecutor
 from concurrent.futures import TimeoutError as FuturesTimeout
@@ -53,6 +63,11 @@ from repro.dist.gnn_step import (DeviceCache, DeviceView,
                                  make_pipelined_epoch, stack_caches)
 from repro.dist.topology import Topology
 from repro.train.checkpoint import save_run_state
+
+
+def _span(name: str) -> jax.profiler.TraceAnnotation:
+    """A host span ``rapidgnn.<name>`` on the profiler's clock."""
+    return jax.profiler.TraceAnnotation("rapidgnn." + name)
 
 
 class StagingError(RuntimeError):
@@ -92,6 +107,11 @@ class DeviceEpochReport:
     #: padded-row split of ``wire_rows`` by tier (flat: all intra)
     intra_wire_rows: int = 0
     inter_wire_rows: int = 0
+    #: rows of the collated ``input_nodes`` that hold a real input node,
+    #: and all of its rows (S * P * m_max): the share of the assemble
+    #: select pass's grid steps that assemble a real row
+    valid_rows: int = 0
+    padded_rows: int = 0
 
     @property
     def total_miss_lanes(self) -> int:
@@ -122,6 +142,8 @@ class DeviceEpochReport:
                 "inter_lanes": [int(x) for x in inter],
                 "intra_wire_rows": int(self.intra_wire_rows),
                 "inter_wire_rows": int(self.inter_wire_rows),
+                "valid_rows": int(self.valid_rows),
+                "padded_rows": int(self.padded_rows),
                 "losses": [float(x) for x in self.losses],
                 "accs": [float(x) for x in self.accs],
                 "wall_time_s": float(self.wall_time_s),
@@ -237,6 +259,9 @@ class _DeviceRunnerBase:
         return [self.dv.remap_cache(es.cache_ids) for es in es_list]
 
     def _counted(self, fn):
+        # keeps ``fn``'s name, so the program is ``jit_epoch_fn`` in
+        # HLO dumps and profiles
+        @functools.wraps(fn)
         def wrapped(*args):
             self.trace_count += 1   # fires once per XLA trace, not per call
             return fn(*args)
@@ -246,9 +271,10 @@ class _DeviceRunnerBase:
 
     def _stage(self, e: int, attempt: int = 0) -> Dict[str, Any]:
         fault_point("stage", attempt=attempt, epoch=e)
-        t0 = time.perf_counter()
-        out = self._stage_inner(e)
-        dt = time.perf_counter() - t0
+        with _span("stage"):
+            t0 = time.perf_counter()
+            out = self._stage_inner(e)
+            dt = time.perf_counter() - t0
         self.stage_time_s += dt
         out["stage_s"] = dt
         return out
@@ -262,10 +288,12 @@ class _DeviceRunnerBase:
         peer is same-host); hierarchical splits by tier, and the tiers
         sum to exactly what the flat plan would count -- the byte-sum
         identity ``verify`` pins (DESIGN.md §6.7)."""
-        batches = collate_device_epoch(
-            es_list, caches, self.dv, self.labels, self.batch_size,
-            self.m_max, self.edge_max, k_max, self.num_steps,
-            topology=self.topo, k_max_inter=k_max_inter)
+        with _span("stage.collate"):
+            batches = collate_device_epoch(
+                es_list, caches, self.dv, self.labels, self.batch_size,
+                self.m_max, self.edge_max, k_max, self.num_steps,
+                topology=self.topo, k_max_inter=k_max_inter)
+        rows = batches["input_nodes"]
         # padded rows the program's all_to_alls move: the pipelined epoch
         # issues one extra pull (the pre-scan pulled0; its final wrap pull
         # is part of the S in-scan pulls), the on-demand epoch exactly S
@@ -286,8 +314,12 @@ class _DeviceRunnerBase:
             _, P_, _, k = batches["send_mask"].shape
             wire_intra = pulls * P_ * P_ * k
             wire_inter = 0
+        with _span("stage.to_device"):
+            dev = jax.tree.map(jnp.asarray, batches)
         return {
-            "batches": jax.tree.map(jnp.asarray, batches),
+            "batches": dev,
+            "valid_rows": int(np.count_nonzero(rows >= 0)),
+            "padded_rows": int(rows.size),
             "lanes": intra + inter,
             "intra_lanes": intra,
             "inter_lanes": inter,
@@ -297,8 +329,10 @@ class _DeviceRunnerBase:
         }
 
     def _stage_inner(self, e: int) -> Dict[str, Any]:
-        es_list = [ws.epoch(e) for ws in self.schedules]
-        caches = self._caches_for(es_list)
+        with _span("stage.schedule"):
+            es_list = [ws.epoch(e) for ws in self.schedules]
+        with _span("stage.caches"):
+            caches = self._caches_for(es_list)
         staged = self._collate_and_account(es_list, caches, self.k_max,
                                            self.k_max_inter)
         if self.uses_cache:
@@ -307,9 +341,11 @@ class _DeviceRunnerBase:
             if fault_point("stage_cache", epoch=e):
                 staged["cache_lost"] = True
             else:
-                cids, cfeats = stack_caches(caches, self.dv, self.n_hot)
-                staged["cids"] = jnp.asarray(cids)
-                staged["cfeats"] = jnp.asarray(cfeats)
+                with _span("stage.stack_caches"):
+                    cids, cfeats = stack_caches(caches, self.dv,
+                                                self.n_hot)
+                    staged["cids"] = jnp.asarray(cids)
+                    staged["cfeats"] = jnp.asarray(cfeats)
         return staged
 
     def _stage_supervised(self, e: int, start_attempt: int = 0
@@ -419,46 +455,53 @@ class _DeviceRunnerBase:
                     self.recovery_wall_s += time.perf_counter() - t_rec
                     self.degraded_epochs += 1
                     degraded, reason = 1, "cache_lost"
-                params, opt_state, losses, accs = self._run_epoch(
-                    params, opt_state, table, offsets, staged)
+                with _span("epoch.dispatch"):
+                    params, opt_state, losses, accs = self._run_epoch(
+                        params, opt_state, table, offsets, staged)
                 # dispatch is async: a background thread stages epoch
                 # e+1 (lazy schedule build + C_sec + plans) WHILE the
                 # device trains epoch e. numpy/XLA release the GIL, so
                 # the two genuinely overlap even single-host ...
                 fut = (pool.submit(self._stage, e + 1, 0)
                        if e + 1 < stop_epoch else None)
-                losses = np.asarray(losses)     # block on the device epoch
-                accs = np.asarray(accs)
+                with _span("epoch.readback"):
+                    losses = np.asarray(losses)  # block on the device epoch
+                    accs = np.asarray(accs)
                 t_done = time.perf_counter()
-                nxt, nxt_retries = ((None, 0) if fut is None
-                                    else self._await_stage(fut, e + 1))
+                with _span("stage.wait"):
+                    nxt, nxt_retries = ((None, 0) if fut is None
+                                        else self._await_stage(fut, e + 1))
                 exposed = (time.perf_counter() - t_done
                            if fut is not None else 0.0)
                 self.exposed_stage_s += exposed
-                reports.append(DeviceEpochReport(
-                    epoch=e, steps=self.num_steps,
-                    miss_lanes=staged["lanes"],
-                    wire_rows=staged["wire_rows"],
-                    intra_lanes=staged.get("intra_lanes"),
-                    inter_lanes=staged.get("inter_lanes"),
-                    intra_wire_rows=staged.get("intra_wire_rows", 0),
-                    inter_wire_rows=staged.get("inter_wire_rows", 0),
-                    losses=losses, accs=accs,
-                    wall_time_s=time.perf_counter() - t0,
-                    stage_s=(nxt["stage_s"] if nxt is not None else 0.0),
-                    exposed_stage_s=exposed,
-                    degraded=degraded, degrade_reason=reason,
-                    stage_retries=pending_retries))
-                self.params, self.opt_state = params, opt_state
-                if (self.checkpoint_dir is not None
-                        and (e + 1) % self.checkpoint_every == 0):
-                    # atomic run-state commit; the crash probe AFTER it
-                    # models dying between epochs -- resume picks up from
-                    # LATEST and the stitched loss curve is bit-equal
-                    save_run_state(self.checkpoint_dir,
-                                   {"params": params, "opt": opt_state},
-                                   step=e + 1)
-                    fault_point("run_crash", epoch=e + 1)
+                with _span("epoch.report"):
+                    reports.append(DeviceEpochReport(
+                        epoch=e, steps=self.num_steps,
+                        miss_lanes=staged["lanes"],
+                        wire_rows=staged["wire_rows"],
+                        intra_lanes=staged.get("intra_lanes"),
+                        inter_lanes=staged.get("inter_lanes"),
+                        intra_wire_rows=staged.get("intra_wire_rows", 0),
+                        inter_wire_rows=staged.get("inter_wire_rows", 0),
+                        valid_rows=staged["valid_rows"],
+                        padded_rows=staged["padded_rows"],
+                        losses=losses, accs=accs,
+                        wall_time_s=time.perf_counter() - t0,
+                        stage_s=(nxt["stage_s"] if nxt is not None
+                                 else 0.0),
+                        exposed_stage_s=exposed,
+                        degraded=degraded, degrade_reason=reason,
+                        stage_retries=pending_retries))
+                    self.params, self.opt_state = params, opt_state
+                    if (self.checkpoint_dir is not None
+                            and (e + 1) % self.checkpoint_every == 0):
+                        # atomic run-state commit; the crash probe AFTER it
+                        # models dying between epochs -- resume picks up from
+                        # LATEST and the stitched loss curve is bit-equal
+                        save_run_state(self.checkpoint_dir,
+                                       {"params": params, "opt": opt_state},
+                                       step=e + 1)
+                        fault_point("run_crash", epoch=e + 1)
                 staged, pending_retries = nxt, nxt_retries
         self.params, self.opt_state = params, opt_state
         return reports

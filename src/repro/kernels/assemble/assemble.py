@@ -56,7 +56,8 @@ def classify(cache_ids: jax.Array, query: jax.Array, base, n_per: int,
     if max(n_hot, n_per) > ROW_MASK:
         raise ValueError(f"row ids past 2**{SRC_SHIFT}: n_hot={n_hot}, "
                          f"n_per={n_per}")
-    pos, hit = search(cache_ids, query, interpret=interpret)
+    pos, hit = search(cache_ids, query, interpret=interpret,
+                      name="assemble_search")
     slot = query - base
     local = (slot >= 0) & (slot < n_per)
     cpos = jnp.minimum(pos, max(n_hot - 1, 0))
@@ -102,6 +103,7 @@ def _select(code, cache3, table3, pulled3, start: int, n: int,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, 1, d), pulled3.dtype),
         interpret=interpret,
+        name="assemble_select",
     )(code[start:start + n], cache3, table3, pulled3)
 
 

@@ -19,7 +19,7 @@ Two fused stages (DESIGN.md §3 kernels):
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -72,9 +72,10 @@ def _search_kernel(q_ref, ids_ref, pos_ref, hit_ref):
 
 
 def search(cache_ids: jax.Array, query: jax.Array, tq: int = DEFAULT_TQ,
-           tc: int = DEFAULT_TC, interpret: bool = False
-           ) -> Tuple[jax.Array, jax.Array]:
+           tc: int = DEFAULT_TC, interpret: bool = False,
+           name: Optional[str] = None) -> Tuple[jax.Array, jax.Array]:
     """cache_ids (n_hot,) sorted int32; query (m,) int32 -> (pos, hit).
+    ``name`` names the Pallas call (and so its op in a profile).
 
     Arbitrary ``m`` / ``n_hot`` (including 0-sized caches) are handled by
     internal padding: queries pad with -1 (matches nothing, pos rows
@@ -104,6 +105,7 @@ def search(cache_ids: jax.Array, query: jax.Array, tq: int = DEFAULT_TQ,
         out_shape=[jax.ShapeDtypeStruct((1, mp), jnp.int32),
                    jax.ShapeDtypeStruct((1, mp), jnp.int32)],
         interpret=interpret,
+        name=name,
     )(query.reshape(1, mp), cache_ids.reshape(n_hot, 1))
     return pos[0, :m], (hit[0, :m] > 0) & (query[:m] != SENTINEL)
 

@@ -109,19 +109,20 @@ def _aggregate(cfg: GNNConfig, layer: int, h: jnp.ndarray,
     dst-major layout with replacement guarantees it), else the
     ``segment_sum`` oracle. Kernel output covers the dst prefix rows
     only -- the tail up to ``m`` is zero on both paths (padded dst rows
-    are fully masked)."""
+    are fully masked). Its ops carry the ``aggregate`` scope."""
     fo = cfg.fanouts[layer] if cfg.fanouts else 0
     E = edge_src.shape[0]
-    if cfg.agg_backend != "segment" and fo > 0 and E % fo == 0:
-        nd = E // fo
-        agg = gather_agg(h, edge_src, edge_mask, nd=nd, fanout=fo,
-                         use_kernel=True,
-                         interpret=cfg.agg_backend == "pallas_interpret")
-        if nd < m:
-            agg = jnp.concatenate(
-                [agg, jnp.zeros((m - nd, h.shape[1]), agg.dtype)])
-        return agg[:m]
-    return aggregate_mean(h, edge_src, edge_dst, edge_mask, m)
+    with jax.named_scope("aggregate"):
+        if cfg.agg_backend != "segment" and fo > 0 and E % fo == 0:
+            nd = E // fo
+            agg = gather_agg(h, edge_src, edge_mask, nd=nd, fanout=fo,
+                             use_kernel=True,
+                             interpret=cfg.agg_backend == "pallas_interpret")
+            if nd < m:
+                agg = jnp.concatenate(
+                    [agg, jnp.zeros((m - nd, h.shape[1]), agg.dtype)])
+            return agg[:m]
+        return aggregate_mean(h, edge_src, edge_dst, edge_mask, m)
 
 
 def forward(cfg: GNNConfig, params: Dict[str, Any],

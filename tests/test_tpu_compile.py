@@ -73,3 +73,52 @@ def test_assemble_select_compiles_for_v5e(one_chip, no_persistent_cache,
              sds((N_PER, D), jnp.float32), sds((), jnp.int32),
              sds((n_hot,), jnp.int32), sds((n_hot, D), jnp.float32),
              sds((m,), jnp.int32), sds((m, D), jnp.float32))
+
+
+def _metric_pattern(name: str) -> str:
+    """The op-name regex of the benchmark's reader ``name``."""
+    import importlib.util
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chipbench", "metrics", name + ".py")
+    spec = importlib.util.spec_from_file_location("metric", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.PATTERN
+
+
+def test_assemble_kernels_keep_their_names_for_v5e(one_chip,
+                                                   no_persistent_cache):
+    """The epoch programs' assembly step at the products cell's widths
+    (m_max 40,960, a 4096-row hot set): its two Pallas calls are named
+    ``assemble_search`` and ``assemble_select``, so the trace reader
+    ``assemble.ms_per_step`` matches exactly them, and both carry the
+    ``assemble`` scope."""
+    import re
+
+    from repro.dist.gnn_step import _assemble
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    m, n_hot = 40_960, 4096
+    text = jax.jit(
+        lambda t, b, c, cf, q, p: _assemble(t, b, c, cf, q, p, "fused",
+                                            False)
+    ).lower(sds((N_PER, D), jnp.float32), sds((), jnp.int32),
+            sds((n_hot,), jnp.int32), sds((n_hot, D), jnp.float32),
+            sds((m,), jnp.int32), sds((m, D), jnp.float32)
+            ).compile().as_text()
+    ops = [line.strip().removeprefix("ROOT ") for line in text.splitlines()
+           if " = " in line]
+    kernels = [op for op in ops if 'custom_call_target="tpu_custom_call"'
+               in op]
+    matched = [op for op in ops
+               if re.search(_metric_pattern("assemble.ms_per_step"), op)]
+    assert matched == kernels
+    assert sorted(op.split(" = ")[0].lstrip("%").split(".")[0]
+                  for op in matched) == ["assemble_search",
+                                         "assemble_select"]
+    for op in matched:
+        op_name = re.search(r'op_name="([^"]*)"', op).group(1)
+        assert "assemble" in op_name.split("/"), op_name
