@@ -31,6 +31,7 @@ into one buffer, bit-equal to the flat pull.
 from __future__ import annotations
 
 import dataclasses
+from typing import Optional
 
 import numpy as np
 import jax
@@ -276,7 +277,8 @@ def pack_pull_lanes_two_tier(ids: np.ndarray, pos: np.ndarray,
 
 def pull_shard(table: jnp.ndarray, send_ids: jnp.ndarray,
                send_pos: jnp.ndarray, send_mask: jnp.ndarray,
-               base, m_max: int, axis="data") -> jnp.ndarray:
+               base, m_max: int, axis="data",
+               width: Optional[int] = None) -> jnp.ndarray:
     """Per-device exchange body; call inside shard_map over ``axis``
     (the flat worker axis ``"data"``, or a mesh-axis tuple like
     ``("dcn", "data")`` whose row-major flattening is the worker order).
@@ -287,21 +289,26 @@ def pull_shard(table: jnp.ndarray, send_ids: jnp.ndarray,
     scattered to ``send_pos`` (other rows zero). Padding lanes may
     request owner-slot 0; the requester's send_mask zeroes them at
     scatter, so the mask never has to cross the wire.
+
+    ``width``: the buffer's lanes, ``d`` or more: rows land in its first
+    ``d`` lanes and the rest stay zero (the fused assembly kernel reads
+    rows padded to whole 128-lane groups, ``kernels/assemble``); only the
+    ``d`` lanes cross the wire.
     """
     n_per, d = table.shape
     req = jax.lax.all_to_all(send_ids, axis, 0, 0)        # (G, k) asks TO me
     slot = jnp.clip(req - base, 0, n_per - 1)
     rows = table[slot]                                    # (G, k, d) serve
     got = jax.lax.all_to_all(rows, axis, 0, 0)            # (G, k, d) mine
-    out = jnp.zeros((m_max, d), table.dtype)
+    out = jnp.zeros((m_max, width or d), table.dtype)
     pos = jnp.where(send_mask, send_pos, 0).reshape(-1)
     contrib = jnp.where(send_mask.reshape(-1, 1), got.reshape(-1, d), 0)
-    return out.at[pos].add(contrib)
+    return out.at[pos, :d].add(contrib)
 
 
 def pull_shard_two_tier(table: jnp.ndarray, send: dict, base, m_max: int,
-                        ici_axis="data",
-                        world_axes=("dcn", "data")) -> jnp.ndarray:
+                        ici_axis="data", world_axes=("dcn", "data"),
+                        width: Optional[int] = None) -> jnp.ndarray:
     """Two-tier exchange body for a hierarchical mesh (DESIGN.md §6.7).
 
     ``send`` holds the two-tier lanes from ``pack_pull_lanes_two_tier``:
@@ -313,9 +320,10 @@ def pull_shard_two_tier(table: jnp.ndarray, send: dict, base, m_max: int,
     miss is same-host xor cross-host) and every real position receives
     exactly one nonzero contribution, so scatter-adding both tiers into
     one zero buffer is bit-equal to the flat single-tier pull.
+    ``width`` as in ``pull_shard``.
     """
     n_per, d = table.shape
-    out = jnp.zeros((m_max, d), table.dtype)
+    out = jnp.zeros((m_max, width or d), table.dtype)
     for pre, axis in (("intra", ici_axis), ("inter", world_axes)):
         sid, spo, sma = (send[f"{pre}_ids"], send[f"{pre}_pos"],
                          send[f"{pre}_mask"])
@@ -324,7 +332,7 @@ def pull_shard_two_tier(table: jnp.ndarray, send: dict, base, m_max: int,
         got = jax.lax.all_to_all(rows, axis, 0, 0)
         pos = jnp.where(sma, spo, 0).reshape(-1)
         contrib = jnp.where(sma.reshape(-1, 1), got.reshape(-1, d), 0)
-        out = out.at[pos].add(contrib)
+        out = out.at[pos, :d].add(contrib)
     return out
 
 

@@ -45,7 +45,7 @@ from jax.flatten_util import ravel_pytree
 
 from repro.core.schedule import EpochSchedule, collate
 from repro.graph.partition import PartitionedGraph
-from repro.kernels.assemble.ops import assemble_features
+from repro.kernels.assemble.ops import assemble_features, source_views
 from repro.kernels.cache_lookup.ops import to_device_ids
 from repro.models.gnn import GNNConfig, loss_fn
 from repro.dist.feature_a2a import (build_pull_plan, pack_pull_lanes,
@@ -514,26 +514,37 @@ def prefetch_stream(send: Dict[str, jnp.ndarray]) -> Dict[str, jnp.ndarray]:
     return out
 
 
-def _pull(tbl, send, base, m_max: int, hier: bool, axis):
+def _pull(tbl, send, base, m_max: int, hier: bool, axis, width: int):
     """One step's residual-miss pull under the ``pull`` scope: the flat
     ``all_to_all`` exchange, or the two-tier one on a hierarchical
-    topology. Shared by both epoch programs."""
+    topology, into a buffer ``width`` lanes wide. Shared by both epoch
+    programs."""
     with jax.named_scope("pull"):
         if hier:
             return pull_shard_two_tier(tbl, send, base, m_max,
-                                       world_axes=axis)
+                                       world_axes=axis, width=width)
         return pull_shard(tbl, send["send_ids"], send["send_pos"],
-                          send["send_mask"], base, m_max)
+                          send["send_mask"], base, m_max, width=width)
+
+
+def _sources(tbl, cfeats, backend: str):
+    """The epoch's table and hot-set rows as the assembly reads them, and
+    the width of the pulled buffer (``source_views``), under the
+    ``assemble`` scope; built once per epoch, outside the step loop."""
+    with jax.named_scope("assemble"):
+        return source_views(tbl, cfeats, backend)
 
 
 def _assemble(tbl, base, cids32, cfeats, ids, pulled, backend: str,
-              interpret: bool):
+              interpret: bool, d: int):
     """One step's feature assembly (``kernels/assemble``) under the
-    ``assemble`` scope. Shared by both epoch programs."""
+    ``assemble`` scope, cut to the model's ``d`` features. Shared by
+    both epoch programs."""
     with jax.named_scope("assemble"):
         return assemble_features(tbl, base, cids32, cfeats,
                                  to_device_ids(ids), pulled,
-                                 backend=backend, interpret=interpret)
+                                 backend=backend, interpret=interpret
+                                 )[:, :d]
 
 
 def _pmean_train_step(cfg: GNNConfig, opt, params, opt_state, feats, x,
@@ -604,8 +615,10 @@ def make_pipelined_epoch(cfg: GNNConfig, opt, mesh, m_max: int,
             cfe = cfeats[0]
             bt = jax.tree.map(lambda a: a[:, 0], bt)   # drop worker dim
 
+            tsrc, csrc, width = _sources(tbl, cfe, assemble_backend)
+
             def pull(send):
-                return _pull(tbl, send, base, m_max, hier, ax)
+                return _pull(tbl, send, base, m_max, hier, ax, width)
 
             send = {k: bt[k] for k in pull_keys}
             # prefetch stream: step i's body pulls step i+1's misses; the
@@ -628,9 +641,10 @@ def make_pipelined_epoch(cfg: GNNConfig, opt, mesh, m_max: int,
             def step(carry, x):
                 params, opt_state, pulled = carry
                 nxt = pull(x["next_send"])        # overlap: no dep on train
-                feats = _assemble(tbl, base, cids32, cfe,
+                feats = _assemble(tsrc, base, cids32, csrc,
                                   x["input_nodes"], pulled,
-                                  assemble_backend, assemble_interpret)
+                                  assemble_backend, assemble_interpret,
+                                  cfg.in_dim)
                 p2, o2, loss, acc = _pmean_train_step(
                     cfg, opt, params, opt_state, feats, x, axis=ax)
                 return (p2, o2, nxt), (loss, acc)
@@ -680,15 +694,16 @@ def make_ondemand_epoch(cfg: GNNConfig, opt, mesh, m_max: int,
             tbl = tbl[0]                          # (n_per, d) my shard
             base = offs.reshape(-1)[0]
             bt = jax.tree.map(lambda a: a[:, 0], bt)   # drop worker dim
+            tsrc, _, width = _sources(tbl, None, assemble_backend)
 
             def step(carry, x):
                 params, opt_state = carry
                 # pull THIS step's remote rows: the train step below
                 # depends on it, so nothing overlaps (on-demand fetch)
-                pulled = _pull(tbl, x, base, m_max, hier, ax)
-                feats = _assemble(tbl, base, None, None, x["input_nodes"],
+                pulled = _pull(tbl, x, base, m_max, hier, ax, width)
+                feats = _assemble(tsrc, base, None, None, x["input_nodes"],
                                   pulled, assemble_backend,
-                                  assemble_interpret)
+                                  assemble_interpret, cfg.in_dim)
                 p2, o2, loss, acc = _pmean_train_step(
                     cfg, opt, params, opt_state, feats, x, axis=ax)
                 return (p2, o2), (loss, acc)

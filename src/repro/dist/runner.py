@@ -109,9 +109,14 @@ class DeviceEpochReport:
     inter_wire_rows: int = 0
     #: rows of the collated ``input_nodes`` that hold a real input node,
     #: and all of its rows (S * P * m_max): the share of the assemble
-    #: select pass's grid steps that assemble a real row
+    #: select pass's rows that assemble a real row
     valid_rows: int = 0
     padded_rows: int = 0
+    #: ``valid_rows`` by the source the assembly copies them from: the
+    #: worker's own shard, its hot set C_s, or the pull (one lane each)
+    local_rows: int = 0
+    cache_rows: int = 0
+    pulled_rows: int = 0
 
     @property
     def total_miss_lanes(self) -> int:
@@ -144,6 +149,9 @@ class DeviceEpochReport:
                 "inter_wire_rows": int(self.inter_wire_rows),
                 "valid_rows": int(self.valid_rows),
                 "padded_rows": int(self.padded_rows),
+                "local_rows": int(self.local_rows),
+                "cache_rows": int(self.cache_rows),
+                "pulled_rows": int(self.pulled_rows),
                 "losses": [float(x) for x in self.losses],
                 "accs": [float(x) for x in self.accs],
                 "wall_time_s": float(self.wall_time_s),
@@ -316,10 +324,21 @@ class _DeviceRunnerBase:
             wire_inter = 0
         with _span("stage.to_device"):
             dev = jax.tree.map(jnp.asarray, batches)
+        # rows by assembly source: the ownership test base <= row <
+        # base + n_per against each worker's base, one pull lane per
+        # pulled row, the hot set the rest
+        base = self.dv.offsets.reshape(1, -1, 1)
+        valid = int(np.count_nonzero(rows >= 0))
+        local = int(np.count_nonzero((rows >= base)
+                                     & (rows < base + self.dv.n_per)))
+        pulled = int((intra + inter).sum())
         return {
             "batches": dev,
-            "valid_rows": int(np.count_nonzero(rows >= 0)),
+            "valid_rows": valid,
             "padded_rows": int(rows.size),
+            "local_rows": local,
+            "cache_rows": valid - local - pulled,
+            "pulled_rows": pulled,
             "lanes": intra + inter,
             "intra_lanes": intra,
             "inter_lanes": inter,
@@ -485,6 +504,9 @@ class _DeviceRunnerBase:
                         inter_wire_rows=staged.get("inter_wire_rows", 0),
                         valid_rows=staged["valid_rows"],
                         padded_rows=staged["padded_rows"],
+                        local_rows=staged["local_rows"],
+                        cache_rows=staged["cache_rows"],
+                        pulled_rows=staged["pulled_rows"],
                         losses=losses, accs=accs,
                         wall_time_s=time.perf_counter() - t0,
                         stage_s=(nxt["stage_s"] if nxt is not None

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.assemble.assemble import assemble as _kernel
+from repro.kernels.assemble.assemble import lane_width, rows_view
 from repro.kernels.assemble.ref import assemble_ref
 from repro.kernels.cache_lookup.ops import cache_lookup
 
@@ -62,6 +63,23 @@ def _staged(table, base, cache_ids, cache_feats, query, pulled,
     return local_merge(table, base, query, merged)
 
 
+def source_views(table: jax.Array, cache_feats: Optional[jax.Array],
+                 backend: str):
+    """-> (table, cache_feats, width): the sources as ``backend`` reads
+    them, and the width of the pulled rows it reads with them. The fused
+    kernel copies whole 128-lane rows, so it takes the lane-padded row
+    views (``rows_view``) and pulled rows ``lane_width(d)`` wide; the
+    other backends take the arrays as they are and ``d``. A caller that
+    assembles many steps from one table builds these once, outside its
+    step loop: inside it, XLA pads the table again on every step."""
+    d = table.shape[-1]
+    if resolve_backend(backend) != "fused":
+        return table, cache_feats, d
+    return (rows_view(table),
+            None if cache_feats is None else rows_view(cache_feats),
+            lane_width(d))
+
+
 @partial(jax.jit, static_argnames=("backend", "interpret"))
 def assemble_features(table: jax.Array, base, cache_ids: Optional[jax.Array],
                       cache_feats: Optional[jax.Array], query: jax.Array,
@@ -73,7 +91,9 @@ def assemble_features(table: jax.Array, base, cache_ids: Optional[jax.Array],
     cache_ids (n_hot,) sorted int32 / None; cache_feats (n_hot, d) /
     None; query (m,) int32 device ids (-1 padded); pulled (m, d) a2a
     residual buffer -> (m, d) assembled rows, priority local > C_s >
-    pulled.
+    pulled. The fused backend also takes ``table`` and ``cache_feats``
+    as their ``rows_view`` and ``pulled`` lane-padded (``source_views``),
+    and then returns rows as wide as ``pulled``.
     """
     backend = resolve_backend(backend)
     if backend == "staged":

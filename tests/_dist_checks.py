@@ -142,6 +142,12 @@ def check_device_runner():
 
     # per-(epoch, worker) residual-miss lanes == host-sim cache_misses
     assert_host_parity(schedules, pg, B, reports)
+    # every valid row is assembled from exactly one source, and each
+    # pulled row rode one lane
+    for r in reports:
+        assert r.local_rows + r.cache_rows + r.pulled_rows == r.valid_rows
+        assert r.pulled_rows == r.total_miss_lanes
+        assert min(r.local_rows, r.cache_rows, r.pulled_rows) > 0
 
     # double-buffer effect: epoch 1 collated against the SWAPPED-in
     # C_sec beats the no-swap counterfactual (stuck on epoch 0's C_s)
@@ -162,6 +168,8 @@ def check_device_runner():
     # no cache: every remote id rides the lanes, so never fewer
     for r, b in zip(reports, rep_b):
         assert b.total_miss_lanes >= r.total_miss_lanes
+        assert (b.local_rows, b.cache_rows, b.pulled_rows) == (
+            r.local_rows, 0, r.cache_rows + r.pulled_rows)
     # identical schedule + exact feature paths => identical curves
     np.testing.assert_allclose(
         np.concatenate([r.losses for r in reports]),

@@ -101,18 +101,90 @@ def test_assemble_awkward_shapes():
     np.testing.assert_array_equal(fused, staged)
 
 
-@pytest.mark.parametrize("rows", [7, 16])
+@pytest.mark.parametrize("rows", [7, 16, 20])
 def test_assemble_select_pass_in_chunks(monkeypatch, rows):
     """Past ``MAX_PREFETCH_ROWS`` query rows the select pass runs in
     several calls (its code vector lives in SMEM); the joined output is
-    bit-equal to the reference."""
+    bit-equal to the reference. Blocks of 8 rows: a chunk of 7 is one
+    short block, 16 two whole ones, 20 two and a partial one, and the
+    last chunk of the 41 rows a single row."""
     from repro.kernels.assemble import assemble as kernel
 
     monkeypatch.setattr(kernel, "MAX_PREFETCH_ROWS", rows)
+    monkeypatch.setattr(kernel, "MAX_BLOCK_ROWS", 8)
     args = _case("mixed", np.random.default_rng(5), m=41)
     ref = np.asarray(assemble_features(*args, backend="ref"))
     fused = np.asarray(kernel.assemble(*args, interpret=True))
     np.testing.assert_array_equal(fused, ref)
+
+
+def _block_case(name, rng):
+    """Query mixes placed against the select pass's blocks of R rows
+    (``block_rows``: 512 at d=100, 96 at d=602)."""
+    from repro.kernels.assemble import assemble as kernel
+
+    kind, d, blocks = SELECT_BLOCK_CASES[name]
+    R = kernel.block_rows(kernel.lane_width(d), 4)
+    m = max(1, int(blocks * R))
+    if kind != "uniform":
+        return _case(kind, rng, n_per=4 * R, d=d, m=m)
+    # the cells' traffic: whole blocks of local rows, then a block mixing
+    # local rows with the step's padding, then a block of padding only
+    table, base, cids, cfeats, q, pulled = _case("all_local", rng,
+                                                 n_per=4 * R, d=d, m=m)
+    tail = np.arange(m) >= 2 * R + 5
+    q = jnp.where(tail, -1, q)
+    pulled = jnp.where(tail[:, None], 0.0, pulled)
+    return table, base, cids, cfeats, q, pulled
+
+
+#: name -> (query mix, d, length in blocks)
+SELECT_BLOCK_CASES = {
+    "below_one_block": ("mixed", 100, 0.2),
+    "one_row_past_a_block_d100": ("mixed", 100, 1 + 1 / 512),
+    "one_row_past_a_block_d602": ("mixed", 602, 1 + 1 / 96),
+    "mixed_blocks_d602": ("mixed", 602, 2.5),
+    "uniform_then_padded_blocks_d100": ("uniform", 100, 4),
+    "uniform_then_padded_blocks_d602": ("uniform", 602, 4),
+}
+
+
+@pytest.mark.parametrize("name", list(SELECT_BLOCK_CASES))
+@pytest.mark.parametrize("views", [False, True])
+def test_assemble_select_blocks_exact(name, views):
+    """The blocked select pass against the reference, bit for bit, at
+    widths that are not multiples of 128: fewer rows than one block, one
+    row past a block boundary, blocks mixing all three sources with
+    padding ids (per-row branch), and whole blocks of one source
+    (branch-free) followed by a padded tail. ``views``: the epoch
+    programs' path -- lane-padded features, the table and hot set passed
+    as their row views -- whose extra lanes come out zero."""
+    from repro.kernels.assemble import assemble as kernel
+    from repro.kernels.assemble.ref import assemble_ref
+
+    args = _block_case(name, np.random.default_rng(len(name)))
+    table, base, cids, cfeats, q, pulled = args
+    d = pulled.shape[1]
+    want = np.asarray(assemble_ref(*args))
+    if views:
+        got = np.asarray(kernel.assemble(
+            kernel.rows_view(table), base, cids, kernel.rows_view(cfeats),
+            q, kernel.pad_lanes(pulled), interpret=True))
+        assert got.shape == (q.shape[0], kernel.lane_width(d))
+        assert not got[:, d:].any()
+        got = got[:, :d]
+    else:
+        got = np.asarray(kernel.assemble(*args, interpret=True))
+    np.testing.assert_array_equal(got.view(np.uint32),
+                                  want.view(np.uint32))
+    if SELECT_BLOCK_CASES[name][0] == "mixed":
+        src = np.asarray(kernel.classify(cids, q, base, table.shape[0],
+                                         interpret=True)) >> kernel.SRC_SHIFT
+        R = kernel.block_rows(kernel.lane_width(d), 4)
+        first = src[:R]
+        assert set(first) == {kernel.SRC_LOCAL, kernel.SRC_CACHE,
+                              kernel.SRC_PULLED}
+        assert (np.asarray(q)[:R] < 0).any()
 
 
 def test_assemble_priority_local_over_cache():

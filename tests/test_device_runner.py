@@ -99,3 +99,32 @@ def test_empty_caches_route_everything_through_lanes(uneven):
                    for b in es.batches)
         got = int(out["send_mask"][:, w].sum())
         assert got == want
+
+
+def test_one_worker_runner_assembles_every_row_locally():
+    """One worker owns the whole graph: the assembly source counters
+    report every valid row local, none cached or pulled, and the report's
+    dict carries them."""
+    from repro.core import build_schedule
+    from repro.dist import DeviceRapidGNNRunner, DeviceView, make_mesh
+    from repro.graph import KHopSampler, load_dataset, partition_graph
+    from repro.models import GNNConfig
+    from repro.train import AdamW
+
+    g = load_dataset("tiny")
+    pg = partition_graph(g, 1, "greedy")
+    sampler = KHopSampler(g, fanouts=[5, 5], batch_size=16)
+    schedules = [build_schedule(sampler, pg, worker=0, s0=3, num_epochs=1,
+                                n_hot=32)]
+    cfg = GNNConfig(kind="sage", in_dim=g.feat_dim, hidden_dim=16,
+                    num_classes=g.num_classes, num_layers=2)
+    runner = DeviceRapidGNNRunner(schedules, DeviceView.build(pg), cfg,
+                                  AdamW(lr=3e-3), make_mesh((1,), ("data",)),
+                                  16, g.labels)
+    (rep,) = runner.run()
+    assert 0 < rep.valid_rows < rep.padded_rows
+    assert (rep.local_rows, rep.cache_rows, rep.pulled_rows) == (
+        rep.valid_rows, 0, 0)
+    d = rep.to_dict()
+    assert (d["local_rows"], d["cache_rows"], d["pulled_rows"]) == (
+        rep.valid_rows, 0, 0)

@@ -8,6 +8,7 @@ a kernel that would fail on the chip without spending chip time. Nothing
 runs; results are covered by the interpret-mode parity suites.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -75,6 +76,95 @@ def test_assemble_select_compiles_for_v5e(one_chip, no_persistent_cache,
              sds((m,), jnp.int32), sds((m, D), jnp.float32))
 
 
+#: the reddit cell's widths: rows six times as wide, 640 lanes padded
+REDDIT = dict(d=602, n_per=60_000, m=22_528, n_hot=4096)
+
+
+@pytest.mark.parametrize("views", [False, True])
+def test_assemble_select_compiles_for_v5e_at_reddit_width(
+        one_chip, no_persistent_cache, views):
+    """The select pass at d=602: from the raw (n_per, 602) arrays, and
+    from the lane-padded row views the epoch programs pass."""
+    from repro.kernels.assemble.assemble import lane_width
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    d, n_per, m, n_hot = (REDDIT[k] for k in ("d", "n_per", "m", "n_hot"))
+    w = lane_width(d) if views else d
+    table, cache = ((sds((n_per, 1, w)), sds((n_hot, 1, w))) if views
+                    else (sds((n_per, w)), sds((n_hot, w))))
+    _compile(lambda t, b, c, cf, q, p: assemble(t, b, c, cf, q, p),
+             table, sds((), jnp.int32), sds((n_hot,), jnp.int32), cache,
+             sds((m,), jnp.int32), sds((m, w)))
+
+
+def _while_bodies(text: str) -> dict:
+    """-> {computation name: its instruction lines} for every while-loop
+    body of an HLO module's text."""
+    names = set(re.findall(r"body=%?([\w.\-]+)", text))
+    bodies, current = {}, None
+    for line in text.splitlines():
+        head = re.match(r"%?([\w.\-]+) \(", line)
+        if head and not line.startswith(" "):
+            current = head.group(1) if head.group(1) in names else None
+            if current:
+                bodies[current] = []
+        elif current and " = " in line:
+            bodies[current].append(line.strip())
+    return bodies
+
+
+#: ops that only pass a loop-invariant buffer through the loop
+_PLUMBING = {"parameter", "get-tuple-element", "tuple", "bitcast"}
+
+
+@pytest.mark.parametrize("cell", ["products", "reddit"])
+def test_assembly_keeps_table_work_out_of_the_step_loop(
+        one_chip, no_persistent_cache, cell):
+    """The epoch programs' assembly inside a ``lax.scan`` over steps, with
+    a loop-invariant table and hot set turned into lane-padded row views
+    before the loop (``_sources``), as the epoch programs do, and pulled
+    rows as wide as the pull makes them: no op of the loop body yields an
+    array with the table's row count, so no step pads, relayouts or
+    copies the table."""
+    from repro.dist.gnn_step import _assemble, _sources
+    from repro.kernels.assemble.assemble import lane_width
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    d, n_per, m, n_hot = ((D, N_PER, 40_960, 4096) if cell == "products"
+                          else (REDDIT[k] for k in
+                                ("d", "n_per", "m", "n_hot")))
+    steps = 2
+
+    def program(table, base, cids, cfeats, queries, pulled):
+        tsrc, csrc, _ = _sources(table, cfeats, "fused")
+
+        def step(acc, x):
+            q, p = x
+            feats = _assemble(tsrc, base, cids, csrc, q, p, "fused", False,
+                              d)
+            return acc + feats.sum(), None
+        return jax.lax.scan(step, 0.0, (queries, pulled))[0]
+
+    text = jax.jit(program).lower(
+        sds((n_per, d)), sds((), jnp.int32), sds((n_hot,), jnp.int32),
+        sds((n_hot, d)), sds((steps, m), jnp.int32),
+        sds((steps, m, lane_width(d)))).compile().as_text()
+    bodies = _while_bodies(text)
+    body_ops = [op for ops in bodies.values() for op in ops]
+    assert any("assemble_select" in op.split(" = ")[0] for op in body_ops)
+    table_sized = []
+    for op in body_ops:
+        found = re.match(r"(?:ROOT )?%?\S+ = (.*?) ([a-z][\w\-]*)\(", op)
+        if (found and found.group(2) not in _PLUMBING
+                and f"[{n_per}," in found.group(1)):
+            table_sized.append(op[:160])
+    assert not table_sized, table_sized
+
+
 def _metric_pattern(name: str) -> str:
     """The op-name regex of the benchmark's reader ``name``."""
     import importlib.util
@@ -94,8 +184,6 @@ def test_assemble_kernels_keep_their_names_for_v5e(one_chip,
     ``assemble_search`` and ``assemble_select``, so the trace reader
     ``assemble.ms_per_step`` matches exactly them, and both carry the
     ``assemble`` scope."""
-    import re
-
     from repro.dist.gnn_step import _assemble
 
     def sds(shape, dtype):
@@ -104,7 +192,7 @@ def test_assemble_kernels_keep_their_names_for_v5e(one_chip,
     m, n_hot = 40_960, 4096
     text = jax.jit(
         lambda t, b, c, cf, q, p: _assemble(t, b, c, cf, q, p, "fused",
-                                            False)
+                                            False, D)
     ).lower(sds((N_PER, D), jnp.float32), sds((), jnp.int32),
             sds((n_hot,), jnp.int32), sds((n_hot, D), jnp.float32),
             sds((m,), jnp.int32), sds((m, D), jnp.float32)
