@@ -32,7 +32,7 @@ epoch, partial last batches with them. It is exact, with the limit 0.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 import jax
@@ -49,10 +49,11 @@ QUIET_LEAF = 1e-3
 
 
 def leaf_norms(tree) -> Dict[str, float]:
-    """{"layer<l>.<leaf>": L2 norm} of a parameter-shaped tree."""
+    """{"layer<l>.<leaf>": L2 norm} of a parameter-shaped tree, over each
+    layer's own leaves."""
     return {f"layer{l}.{k}": float(np.linalg.norm(
         np.asarray(layer[k], np.float64)))
-        for l, layer in enumerate(tree["layers"]) for k in reference.LEAVES}
+        for l, layer in enumerate(tree["layers"]) for k in sorted(layer)}
 
 
 def worst_leaf_gap(prog: Dict[str, float], ref: Dict[str, float],
@@ -208,19 +209,20 @@ def pack_steps(flats: Sequence, labels: np.ndarray, batch_size: int,
 
 def reference_readings(steps: List[List[dict]], table: jax.Array,
                        params0, hp: tuple, steps_per_epoch: int,
-                       dtype=jnp.float32,
+                       loss_and_grad: Callable, dtype=jnp.float32,
                        update=reference.adamw_steps) -> dict:
     """Replay the check on the reference: real step 1, the rest of epoch
     A masked, real steps 2 and 3, the rest of epoch B masked.
-    ``hp`` = (lr, b1, b2, eps, weight_decay); ``update`` is the
-    optimizer (``reference.adamw_steps``'s signature)."""
+    ``hp`` = (lr, b1, b2, eps, weight_decay); ``loss_and_grad`` is the
+    model's (``chipbench/models/<model>.py``), ``update`` the optimizer
+    (``reference.adamw_steps``'s signature)."""
     S = steps_per_epoch
     zero_after = (S - 1, 0, S - 2)
     params = params0
     state = reference.adamw_init(params0)
     losses, grad1 = [], None
     for j, per_worker in enumerate(steps):
-        outs = [reference.loss_and_grad(
+        outs = [loss_and_grad(
             params, table, w["rows"], w["edges"], w["labels"],
             w["seed_mask"], dtype=dtype) for w in per_worker]
         loss = sum(o[0] for o in outs) / len(outs)

@@ -3,34 +3,26 @@ program does it: padded rows, rows past a layer's dst prefix and
 recomputed work never count, so a later kernel or layout is measured
 against the same work.
 
-Per worker-batch, with ``nd[l]`` the dst rows of layer ``l``, ``e[l]``
-its valid edges and ``d[l] -> d[l+1]`` its widths:
+Per worker-epoch:
 
-* FLOPs forward = sum_l nd[l] * 2*d[l]*d[l+1] * 2 (the self and neighbour
-  products) + e[l] * d[l] (the mean aggregation's adds); forward plus
-  backward is three times forward.
+* FLOPs: forward plus backward, by the model's own count
+  (``epoch_flops`` of ``chipbench/models/<model>.py``).
 * assemble bytes = m * (2 * d[0] * 4 + 4): every valid input row read
   once and written once in float32, plus its int32 query id.
 """
 from __future__ import annotations
 
-from typing import Dict, Sequence
-
-import numpy as np
+from typing import Callable, Dict
 
 
-def epoch_counts(flat, dims: Sequence[int]) -> Dict[str, float]:
+def epoch_counts(flat, feat_dim: int,
+                 flops: Callable[[object], float]) -> Dict[str, float]:
     """-> {"flops", "assemble_bytes", "seeds", "rows"} summed over the
-    batches of one worker-epoch (a ``FlatEpoch``)."""
-    flops = 0.0
-    for l in range(len(dims) - 1):
-        nd = flat.num_dst[l].astype(np.float64)
-        edges = float(np.count_nonzero(flat.edge_mask[l]))
-        flops += float(nd.sum()) * 2 * dims[l] * dims[l + 1] * 2
-        flops += edges * dims[l]
+    batches of one worker-epoch (a ``FlatEpoch``); ``flops(flat)`` is
+    the model's count for it."""
     rows = float(flat.m_counts.sum())
-    return {"flops": 3.0 * flops,
-            "assemble_bytes": rows * (2 * dims[0] * 4 + 4),
+    return {"flops": flops(flat),
+            "assemble_bytes": rows * (2 * feat_dim * 4 + 4),
             "seeds": float(flat.seeds.shape[0]),
             "rows": rows}
 

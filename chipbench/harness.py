@@ -6,10 +6,11 @@ What the window drives is the program's own training loop,
 background thread, a prefetched ``all_to_all`` pull), in this process,
 on the cell's chips. The scenario is built as the program's device cells
 build it: ``partition_graph``, ``KHopSampler``, ``build_schedule``
-(numpy, eager), ``DeviceView``, a ``GNNConfig`` of the configuration's
-``model`` (the one ``chipbench.reference`` implements) and AdamW.
-The graph comes from ``chipbench.graph``, the weights from
-``chipbench.reference.init_params``.
+(numpy, eager), ``DeviceView``, the model's ``GNNConfig`` and AdamW.
+The model is the module ``models/<model>.py``, found by the
+configuration's ``model``: the weights, the program's configuration,
+the FLOP count and the reference come from it. The graph comes from
+``chipbench.graph``.
 
 The runner walks every epoch of its schedules when it is built and
 stages its first epoch synchronously on every ``run()`` call. So each
@@ -27,6 +28,7 @@ run compiles.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -39,7 +41,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
-from chipbench import check, counts, reference
+from chipbench import check, counts
 from chipbench import trace as trace_mod
 from chipbench.graph import DatasetSpec, make_graph
 
@@ -80,14 +82,38 @@ def load_cell(bench: Dict[str, Any], name: str, root: str) -> Cell:
         end_to_end=bench["end_to_end"], per_layer=bench["per_layer"])
 
 
-def load_reader(metric: str) -> Callable:
-    """``metrics/<name>.py``'s ``read(run) -> float | None``."""
-    path = os.path.join(HERE, "metrics", metric + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + metric.replace(".", "_"), path)
+def _load(path: str, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(metric: str) -> Callable:
+    """``metrics/<name>.py``'s ``read(run) -> float | None``."""
+    return _load(os.path.join(HERE, "metrics", metric + ".py"),
+                 "chipbench_metric_" + metric.replace(".", "_")).read
+
+
+#: where a configuration's ``model`` is looked up, as ``<model>.py``
+MODELS = os.path.join(HERE, "models")
+
+
+def load_model(model: str):
+    """The module of a configuration's ``model``: ``init_params(config,
+    seed)``, ``loss_and_grad``, ``gnn_config(config)`` and
+    ``epoch_flops(config, flat)`` (see ``models/``). Loaded once per
+    file, so its jitted functions keep their compiled programs."""
+    path = os.path.join(MODELS, model + ".py")
+    if not os.path.isfile(path):
+        raise ValueError(f"model {model!r}: no {path}")
+    return _model_at(path)
+
+
+@functools.lru_cache(maxsize=None)
+def _model_at(path: str):
+    name = os.path.splitext(os.path.basename(path))[0]
+    return _load(path, "chipbench_model_" + name.replace(".", "_"))
 
 
 def peaks_for(kind: str) -> Dict[str, Any]:
@@ -102,12 +128,6 @@ def peaks_for(kind: str) -> Dict[str, Any]:
 def dataset_spec(config: Dict[str, Any]) -> DatasetSpec:
     return DatasetSpec(**{f.name: config[f.name]
                           for f in dataclasses.fields(DatasetSpec)})
-
-
-def dims(config: Dict[str, Any]) -> List[int]:
-    return ([config["feat_dim"]]
-            + [config["hidden_dim"]] * (config["num_layers"] - 1)
-            + [config["num_classes"]])
 
 
 def build_graph(config: Dict[str, Any]):
@@ -195,6 +215,7 @@ class Setup:
     views: List[Any]
     runner: Any
     hp: tuple                    # AdamW (lr, b1, b2, eps, weight_decay)
+    model: Any                   # the configuration's model module
 
 
 def build(cell: Cell, seed: int, epochs: int,
@@ -207,15 +228,12 @@ def build(cell: Cell, seed: int, epochs: int,
     from repro.core import build_schedule
     from repro.dist import DeviceRapidGNNRunner, DeviceView, Topology
     from repro.graph import KHopSampler, partition_graph
-    from repro.models import GNNConfig
     from repro.train import AdamW
 
     conf, traffic, floor = cell.config, cell.traffic, cell.bounds["pad_floor"]
     P, batch = traffic["workers"], traffic["batch_size"]
     opt = conf["optimizer"]
-    if conf["model"] != reference.MODEL:
-        raise ValueError(f"model {conf['model']!r}: the reference in "
-                         f"chipbench/reference.py is {reference.MODEL!r}")
+    model = load_model(conf["model"])
     g, arrays = build_graph(conf) if graph is None else (graph, arrays)
     pg = (partition_graph(g, P, traffic["partition"]) if partition is None
           else partition)
@@ -225,12 +243,8 @@ def build(cell: Cell, seed: int, epochs: int,
                  for w in range(P)]
     views = build_views(schedules, floor)
     topo = Topology.flat(P)
-    gcfg = GNNConfig(kind=conf["model"], in_dim=g.feat_dim,
-                     hidden_dim=conf["hidden_dim"],
-                     num_classes=g.num_classes,
-                     num_layers=conf["num_layers"])
     runner = DeviceRapidGNNRunner(
-        views, DeviceView.build(pg), gcfg,
+        views, DeviceView.build(pg), model.gnn_config(conf),
         AdamW(lr=opt["lr"], b1=opt["b1"], b2=opt["b2"], eps=opt["eps"],
               weight_decay=opt["weight_decay"]),
         topo.make_mesh(), batch, g.labels, seed=seed, topology=topo)
@@ -242,7 +256,7 @@ def build(cell: Cell, seed: int, epochs: int,
     hp = (opt["lr"], opt["b1"], opt["b2"], opt["eps"], opt["weight_decay"])
     return Setup(cell=cell, seed=seed, graph=g, arrays=arrays,
                  partition=pg, schedules=schedules, views=views,
-                 runner=runner, hp=hp)
+                 runner=runner, hp=hp, model=model)
 
 
 def train_check(s: Setup) -> Dict[str, Any]:
@@ -253,7 +267,7 @@ def train_check(s: Setup) -> Dict[str, Any]:
     import jax
 
     runner = s.runner
-    p0 = reference.init_params(dims(s.cell.config), s.seed)
+    p0 = s.model.init_params(s.cell.config, s.seed)
     p0_host = jax.device_get(p0)
     rep_a = runner.run(params=p0, start_epoch=0, stop_epoch=1)
     grad1 = check.grad_from_first_moment(
@@ -293,8 +307,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
 
     E = cell.traffic["epochs"]
     s = build(cell, seed, E, log=log)
-    runner, views, schedules, g, hp = (s.runner, s.views, s.schedules,
-                                       s.graph, s.hp)
+    runner, views, schedules, g, hp, model = (
+        s.runner, s.views, s.schedules, s.graph, s.hp, s.model)
     P, S = cell.traffic["workers"], runner.num_steps
     prog = train_check(s)
 
@@ -307,10 +321,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     n = max(2, math.ceil(seconds / epoch_s))
     start = extend_views(runner, views, E, n)
     per_epoch = [{} for _ in range(E)]
+    flops = functools.partial(model.epoch_flops, cell.config)
     for ws in schedules:
         for e in range(E):
             per_epoch[e] = counts.add(per_epoch[e], counts.epoch_counts(
-                ws.epoch(e).flat, dims(cell.config)))
+                ws.epoch(e).flat, cell.config["feat_dim"], flops))
     work: Dict[str, float] = {}
     for i in range(n):
         work = counts.add(work, per_epoch[(start - 2 + i) % E])
@@ -364,7 +379,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     table = jax.device_put(g.features, devices[0])
     ref = check.reference_readings(
         steps, table, jax.device_put(prog["params0"], devices[0]),
-        hp, S_ref)
+        hp, S_ref, model.loss_and_grad)
     numbers = dict(check.compare(prog, ref), blocks=blocks)
     failed = int(np.count_nonzero(~np.isfinite(losses)))
     limits = cell.bounds["limits"]

@@ -1,7 +1,7 @@
 """Model FLOP/s utilization of the window, %: the FLOPs the forward and
-backward passes require (``chipbench.counts``: the valid dst rows' two
-products per layer plus the mean aggregation's adds, backward twice
-forward) over the window's wall time, chips and the chip's peak."""
+backward passes require (``chipbench.counts``, by the model's own count
+in ``chipbench/models/<model>.py``) over the window's wall time, chips
+and the chip's peak."""
 
 
 def read(run):

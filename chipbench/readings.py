@@ -1,6 +1,6 @@
 """The readings a cell's limits are set from (``check.py``).
 
-  python -m chipbench.readings --workload sage-products.p1 \\
+  python -m chipbench.readings --workload <cell> \\
       --seeds 12 --control-seeds 3 --out readings.jsonl
 
 On the cell's chips, for each seed: the program's check epochs against
@@ -13,8 +13,8 @@ the reference (the upper readings):
 ``exchange``  the rows a worker pulls over the all_to_all left out
               (neither local nor in its steady cache; four chips only);
 ``answer``    the step's answer altered where it is produced: the
-              optimizer applies one leaf's update (layer 0's
-              ``w_neigh``) twice.
+              optimizer applies one leaf's update (``answer_leaf``:
+              layer 0's largest) twice.
 
 A state left unchanged reads 1 on ``grad`` and ``update`` by their
 measure and needs no run. The benchmark's own runs never run this. One
@@ -55,13 +55,19 @@ def plant(steps, fault: str, setup: "harness.Setup"):
     return out
 
 
+def answer_leaf(layer) -> str:
+    """The leaf of a layer whose update ``answer`` doubles: the largest,
+    the first by name among equals."""
+    return min(layer, key=lambda k: (-layer[k].size, k))
+
+
 def doubled_leaf_update(params, state, grads, zero_steps, *, hp):
-    """AdamW that moves layer 0's ``w_neigh`` twice as far."""
+    """AdamW that moves layer 0's ``answer_leaf`` twice as far."""
     new, state = reference.adamw_steps(params, state, grads, zero_steps,
                                        hp=hp)
-    old = params["layers"][0]["w_neigh"]
-    new["layers"][0]["w_neigh"] = old + 2 * (new["layers"][0]["w_neigh"]
-                                             - old)
+    k = answer_leaf(params["layers"][0])
+    old = params["layers"][0][k]
+    new["layers"][0][k] = old + 2 * (new["layers"][0][k] - old)
     return new, state
 
 
@@ -90,7 +96,8 @@ def read_cell(cell: "harness.Cell", device: dict, seeds: int,
             gc.collect()
             table = jax.device_put(s.graph.features, dev0)
             p0 = jax.device_put(prog["params0"], dev0)
-            ref = check.reference_readings(steps, table, p0, s.hp, S)
+            lg = s.model.loss_and_grad
+            ref = check.reference_readings(steps, table, p0, s.hp, S, lg)
             rec = {"workload": cell.name, "seed": seed, "device": device,
                    "program": check.compare(prog, ref),
                    "losses": {"program": prog["losses"],
@@ -98,15 +105,16 @@ def read_cell(cell: "harness.Cell", device: dict, seeds: int,
                    "grad_norms": check.leaf_norms(ref["grad1"])}
             if i < control_seeds:
                 ctrl = check.reference_readings(steps, table, p0, s.hp, S,
-                                                dtype=jnp.bfloat16)
+                                                lg, dtype=jnp.bfloat16)
                 rec["control"] = check.compare(ctrl, ref)
                 rec["control_losses"] = ctrl["losses"]
                 bad = check.reference_readings(
-                    steps, table, p0, s.hp, S, update=doubled_leaf_update)
+                    steps, table, p0, s.hp, S, lg,
+                    update=doubled_leaf_update)
                 rec["answer"] = check.compare(bad, ref)
                 for f in faults:
                     bad = check.reference_readings(plant(steps, f, s),
-                                                   table, p0, s.hp, S)
+                                                   table, p0, s.hp, S, lg)
                     rec[f] = check.compare(bad, ref)
             rec["seconds"] = time.perf_counter() - t0
             print(json.dumps(rec), file=out, flush=True)

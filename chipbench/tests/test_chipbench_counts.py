@@ -1,8 +1,19 @@
 """The FLOP and byte counters against hand counts for one tiny batch."""
+import functools
+
 import numpy as np
 import pytest
 
-from chipbench import counts
+from chipbench import counts, harness
+
+#: widths [4, 3, 2]
+CONFIG = {"model": "sage", "feat_dim": 4, "hidden_dim": 3,
+          "num_classes": 2, "num_layers": 2}
+
+
+def sage_counts(flat):
+    flops = functools.partial(harness.load_model("sage").epoch_flops, CONFIG)
+    return counts.epoch_counts(flat, CONFIG["feat_dim"], flops)
 
 
 def one_batch_epoch():
@@ -26,7 +37,7 @@ def one_batch_epoch():
 
 
 def test_hand_counts_for_one_batch():
-    c = counts.epoch_counts(one_batch_epoch(), [4, 3, 2])
+    c = sage_counts(one_batch_epoch())
     # layer 0: 3 dst rows x (2*4*3) x 2 products + 3 valid edges x 4 adds
     # layer 1: 2 dst rows x (2*3*2) x 2 products + 2 edges x 3 adds
     forward = 3 * 24 * 2 + 3 * 4 + 2 * 12 * 2 + 2 * 3
@@ -36,6 +47,6 @@ def test_hand_counts_for_one_batch():
 
 
 def test_counts_add_up_over_an_epoch():
-    a = counts.epoch_counts(one_batch_epoch(), [4, 3, 2])
+    a = sage_counts(one_batch_epoch())
     total = counts.add(counts.add({}, a), a)
     assert total == {k: 2 * v for k, v in a.items()}

@@ -48,7 +48,9 @@ def test_reference_adamw_matches_the_program_optimizer():
     import jax
     from repro.train import AdamW
 
-    params = reference.init_params([6, 5, 3], 2 ** 40 + 3)
+    params = harness.load_model("sage").init_params(
+        {"feat_dim": 6, "hidden_dim": 5, "num_classes": 3,
+         "num_layers": 2}, 2 ** 40 + 3)
     grads = jax.tree.map(lambda p: p * 0.5 + 0.01, params)
     opt = AdamW(lr=3e-3)
     p, st = params, opt.init(params)
@@ -70,7 +72,8 @@ def test_reference_matches_the_runner_on_one_worker():
     prog = harness.train_check(s)
     ref = check.reference_readings(
         harness.check_steps(s), jax.numpy.asarray(s.graph.features),
-        prog["params0"], s.hp, harness.steps_per_epoch(s))
+        prog["params0"], s.hp, harness.steps_per_epoch(s),
+        s.model.loss_and_grad)
     numbers = check.compare(prog, ref)
     assert all(v < 1e-5 for v in numbers.values()), numbers
     assert check.judge(numbers, _tiny.LIMITS)
