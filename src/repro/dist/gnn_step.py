@@ -25,15 +25,15 @@ computes the exact static lane bound; ``collate_device_epoch`` packs a
 whole epoch into (S, P, ...) arrays in one VECTORIZED pass (single
 ``g2d`` gather over the schedule compiler's FlatEpoch streams, one
 stamp-table membership pass per worker, batched lane packing,
-boolean-mask slab fills for every ragged array -- DESIGN.md §6.6; the
-per-(step,
-worker) loop survives as ``collate_device_epoch_loop``, the
+threaded slice copies into the padded slabs -- DESIGN.md §6.6; the
+per-(step, worker) loop survives as ``collate_device_epoch_loop``, the
 parity/bench reference); ``stack_caches`` stacks the per-worker hot
 sets C_s.
 """
 from __future__ import annotations
 
 import dataclasses
+from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
@@ -274,18 +274,21 @@ def epoch_k_max_split(es_list: Sequence[EpochSchedule],
 
 def _alloc_epoch(P_: int, S: int, batch_size: int, m_max: int,
                  edge_max: Sequence[int], k_max: int, topology=None,
-                 k_max_inter: Optional[int] = None
+                 k_max_inter: Optional[int] = None,
+                 with_edge_dst: bool = True
                  ) -> Dict[str, np.ndarray]:
     """Empty (S, P, ...) device-layout epoch: every step fully masked.
     With a hierarchical ``topology`` the pull lanes split into the
     two-tier layout -- intra (S, P, D, k_max) + inter (S, P, P,
-    k_max_inter) -- instead of the flat send_* (S, P, P, k_max)."""
+    k_max_inter) -- instead of the flat send_* (S, P, P, k_max).
+    ``with_edge_dst=False``: ``edge_dst`` is ``None`` per layer."""
     out = {
         "input_nodes": np.full((S, P_, m_max), -1, np.int64),
         "labels": np.zeros((S, P_, batch_size), np.int32),
         "seed_mask": np.zeros((S, P_, batch_size), bool),
         "edge_src": [np.zeros((S, P_, e), np.int32) for e in edge_max],
-        "edge_dst": [np.zeros((S, P_, e), np.int32) for e in edge_max],
+        "edge_dst": [np.zeros((S, P_, e), np.int32) if with_edge_dst
+                     else None for e in edge_max],
         "edge_mask": [np.zeros((S, P_, e), bool) for e in edge_max],
     }
     if topology is not None and topology.is_hierarchical:
@@ -313,12 +316,38 @@ def _check_num_steps(es_list: Sequence[EpochSchedule], S: int) -> None:
             f"(dropping steps would corrupt miss accounting)")
 
 
+#: threads that copy a collated epoch's rows (``_submit_fills``)
+FILL_THREADS = 4
+
+
+def _copy_rows(slab, stream, starts, lo: int, hi: int) -> None:
+    """Rows ``lo..hi-1`` of the ``(S, K)`` slab: row ``i`` takes
+    ``stream[starts[i]:starts[i + 1]]`` as its prefix."""
+    bounds = starts[lo:hi + 1].tolist()
+    for i, (a, b) in enumerate(zip(bounds[:-1], bounds[1:]), lo):
+        slab[i, :b - a] = stream[a:b]
+
+
+def _submit_fills(pool: ThreadPoolExecutor, fills) -> list:
+    """Submit each ``(slab, stream, starts)``'s row copies to ``pool``,
+    its rows split into ``FILL_THREADS`` shares -> the futures."""
+    futs = []
+    for slab, stream, starts in fills:
+        cuts = np.linspace(0, starts.shape[0] - 1,
+                           FILL_THREADS + 1).astype(int)
+        futs += [pool.submit(_copy_rows, slab, stream, starts, a, b)
+                 for a, b in zip(cuts[:-1], cuts[1:]) if b > a]
+    return futs
+
+
 def collate_device_epoch(es_list: Sequence[EpochSchedule],
                          caches: Sequence[DeviceCache], dv: DeviceView,
                          labels: np.ndarray, batch_size: int, m_max: int,
                          edge_max: Sequence[int], k_max: int,
                          num_steps: int, topology=None,
-                         k_max_inter: Optional[int] = None
+                         k_max_inter: Optional[int] = None,
+                         with_edge_dst: bool = True,
+                         stats: Optional[Dict[str, int]] = None
                          ) -> Dict[str, np.ndarray]:
     """Pack an epoch into the (S, P, ...) device layout -- VECTORIZED.
 
@@ -335,12 +364,10 @@ def collate_device_epoch(es_list: Sequence[EpochSchedule],
     (``_classify_misses``, replacing S x P ``np.isin`` re-sorts), one
     sort-based lane packing (``pack_pull_lanes``) replacing S x P
     ``build_pull_plan`` calls, and -- now that the schedule compiler
-    stores each worker-epoch as a FlatEpoch -- ONE boolean-mask
-    assignment per (worker, output array) for the ragged padded fills,
-    streaming each worker's flat arrays into its padded slab in C
-    order, replacing the last per-batch memcpy loop. This is what
-    keeps the host's double-buffer staging ahead of the device at 256+
-    workers.
+    stores each worker-epoch as a FlatEpoch -- one slice copy per
+    (batch, output array) from the worker's flat stream into its
+    padded slab, on ``FILL_THREADS`` threads (``_submit_fills``). This is
+    what keeps the host's double-buffer staging ahead of the device.
 
     ``m_max``/``edge_max``/``k_max``/``num_steps`` are precomputed
     bounds -- the multi-epoch runner passes GLOBAL (all-epoch, all-
@@ -356,75 +383,110 @@ def collate_device_epoch(es_list: Sequence[EpochSchedule],
     (``intra_*``/``inter_*`` via ``pack_pull_lanes_two_tier``, bounds
     ``k_max``/``k_max_inter``) instead of flat ``send_*`` -- everything
     else (batches, labels, edges) is layout-identical.
+
+    ``with_edge_dst=False`` leaves ``edge_dst`` out (``None`` per
+    layer), for a program whose aggregation reads the dst rows off the
+    fan-out-regular layout (``models.gnn.scatter_free_layers``). A
+    ``stats`` dict receives the epoch's ``valid_rows`` (input rows with
+    a node) and ``local_rows`` (those the requesting worker's shard
+    holds), counted on the flat streams.
     """
     P_ = len(es_list)
     S = num_steps
     _check_num_steps(es_list, S)
-    hier = topology is not None and topology.is_hierarchical
     out = _alloc_epoch(P_, S, batch_size, m_max, edge_max, k_max,
-                       topology=topology, k_max_inter=k_max_inter)
+                       topology=topology, k_max_inter=k_max_inter,
+                       with_edge_dst=with_edge_dst)
+    if stats is not None:
+        stats.update(valid_rows=0, local_rows=0)
     flat = _epoch_flat(es_list, dv)
     if flat is None:
         return out
-    flats = flat["flats"]
-    row = flat["step"] * P_ + flat["worker"]    # batch -> flat (step, w)
-    dev, starts = flat["dev"], flat["starts"]
+    flats, dev = flat["flats"], flat["dev"]
 
-    # ragged padded fills: per worker slab, ONE boolean-mask assignment
-    # per output array. The mask `arange(K) < counts[:, None]` iterates
-    # the (S, K) slab in C order, which is exactly the worker's flat
-    # stream order, so `slab[valid] = stream` is a single compiled
-    # sequential copy -- no per-batch loop, no index arrays (an
-    # int64-index scatter moves 3x the bytes and measured ~2x slower)
-    def _pad_counts(cnts: np.ndarray) -> np.ndarray:
-        full = np.zeros(S, np.int64)
-        full[:cnts.shape[0]] = cnts
-        return full
-
+    # ragged padded fills: each batch's valid prefix is one contiguous
+    # slice of its worker's flat stream (CSR offsets), copied into its
+    # row of the pre-padded slab; the padded tails keep _alloc_epoch's
+    # fill. The copies release the GIL, so they run on a few threads,
+    # a share of every array's rows each, while this thread packs the
+    # pull lanes and counts the rows from the flat streams
+    fills = []
     lo = 0
     for w, f in enumerate(flats):
         if f.num_batches == 0:
             continue    # fully masked worker; may carry 0 layer info
         span = int(f.input_starts[-1])
-        valid = np.arange(m_max) < _pad_counts(f.m_counts)[:, None]
-        out["input_nodes"][:, w][valid] = dev[lo:lo + span]
+        fills.append((out["input_nodes"][:, w], dev[lo:lo + span],
+                      f.input_starts))
         lo += span
-        svalid = np.arange(batch_size) < \
-            _pad_counts(np.diff(f.seed_starts))[:, None]
-        out["labels"][:, w][svalid] = labels[f.seeds]
-        out["seed_mask"][:, w][svalid] = True
+        fills.append((out["labels"][:, w], labels[f.seeds],
+                      f.seed_starts))
+        fills.append((out["seed_mask"][:, w],
+                      np.ones(f.seeds.shape[0], bool), f.seed_starts))
         for l in range(len(edge_max)):
-            evalid = np.arange(edge_max[l]) < \
-                _pad_counts(np.diff(f.edge_starts[l]))[:, None]
-            out["edge_src"][l][:, w][evalid] = f.edge_src[l]
-            out["edge_dst"][l][:, w][evalid] = f.edge_dst[l]
-            out["edge_mask"][l][:, w][evalid] = f.edge_mask[l]
+            for key, stream in (("edge_src", f.edge_src[l]),
+                                ("edge_dst", f.edge_dst[l]),
+                                ("edge_mask", f.edge_mask[l])):
+                if out[key][l] is not None:
+                    fills.append((out[key][l][:, w], stream,
+                                  f.edge_starts[l]))
+    with ThreadPoolExecutor(FILL_THREADS) as pool:
+        copies = _submit_fills(pool, fills)
+        out.update(_pull_lanes(flat, caches, dv, S, P_, k_max, topology,
+                               k_max_inter))
+        if stats is not None:
+            stats["valid_rows"] = int(dev.shape[0])
+            stats["local_rows"] = _local_rows(flat, dv)
+        for c in copies:
+            c.result()
+    return out
 
-    # residual-miss pull lanes: one classification + one batched packing
+
+def _pull_lanes(flat, caches: Sequence[DeviceCache], dv: DeviceView,
+                S: int, P_: int, k_max: int, topology,
+                k_max_inter: Optional[int]) -> Dict[str, np.ndarray]:
+    """The epoch's residual-miss pull lanes, (S, P, ...): one
+    classification + one batched packing, flat ``send_*`` or, on a
+    hierarchical ``topology``, two-tier ``intra_*``/``inter_*``."""
+    dev = flat["dev"]
+    row = flat["step"] * P_ + flat["worker"]    # batch -> flat (step, w)
     miss, owner_miss = _classify_misses(flat, caches, dv)
     eb, col = _miss_coords(flat, miss)
     # assume_unique: the sampler dedupes input_nodes per batch, so no
     # (group, id, pos) duplicates can exist
-    if hier:
+    if topology is not None and topology.is_hierarchical:
         D = topology.devices_per_host
         k_x = k_max_inter if k_max_inter is not None else k_max
         intra, inter = pack_pull_lanes_two_tier(
             dev[miss], col, row[eb], owner_miss, flat["worker"][eb],
             S * P_, topology, k_max, k_x, assume_unique=True)
-        out["intra_ids"] = intra[0].reshape(S, P_, D, k_max)
-        out["intra_pos"] = intra[1].reshape(S, P_, D, k_max)
-        out["intra_mask"] = intra[2].reshape(S, P_, D, k_max)
-        out["inter_ids"] = inter[0].reshape(S, P_, P_, k_x)
-        out["inter_pos"] = inter[1].reshape(S, P_, P_, k_x)
-        out["inter_mask"] = inter[2].reshape(S, P_, P_, k_x)
-        return out
+        return {"intra_ids": intra[0].reshape(S, P_, D, k_max),
+                "intra_pos": intra[1].reshape(S, P_, D, k_max),
+                "intra_mask": intra[2].reshape(S, P_, D, k_max),
+                "inter_ids": inter[0].reshape(S, P_, P_, k_x),
+                "inter_pos": inter[1].reshape(S, P_, P_, k_x),
+                "inter_mask": inter[2].reshape(S, P_, P_, k_x)}
     sids, spos, smask, _ = pack_pull_lanes(
         dev[miss], col, row[eb], owner_miss, S * P_, P_, k_max,
         assume_unique=True)
-    out["send_ids"] = sids.reshape(S, P_, P_, k_max)
-    out["send_pos"] = spos.reshape(S, P_, P_, k_max)
-    out["send_mask"] = smask.reshape(S, P_, P_, k_max)
-    return out
+    return {"send_ids": sids.reshape(S, P_, P_, k_max),
+            "send_pos": spos.reshape(S, P_, P_, k_max),
+            "send_mask": smask.reshape(S, P_, P_, k_max)}
+
+
+def _local_rows(flat, dv: DeviceView) -> int:
+    """Input rows of the epoch that their own worker's shard holds:
+    ``base <= id < base + n_per`` against the requesting worker's
+    base, over the flat (unpadded) streams."""
+    dev, wk, mc = flat["dev"], flat["worker"], flat["m_counts"]
+    n, lo = 0, 0
+    for w in range(len(flat["flats"])):
+        span = int(mc[wk == w].sum())
+        d = dev[lo:lo + span]
+        lo += span
+        base = w * dv.n_per
+        n += int(np.count_nonzero((d >= base) & (d < base + dv.n_per)))
+    return n
 
 
 def collate_device_epoch_loop(es_list: Sequence[EpochSchedule],

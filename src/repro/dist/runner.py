@@ -26,6 +26,14 @@ with EMPTY caches: no C_s, no software pipeline, every remote id pulled
 on the critical path -- the DGL-style on-demand path, so device
 rapid-vs-baseline step time is measurable on the same mesh.
 
+The epoch program's configuration comes from the schedule as well: where
+every batch of every (worker, epoch) has exactly ``num_dst * F`` edges in
+a layer, for one ``F`` per layer, the runner hands the program those
+fan-outs (``epoch_fanouts``), so SAGE/GCN's mean takes the
+scatter-free reshape path (``models/gnn.py``) and the stage leaves the
+unread ``edge_dst`` out; an irregular or empty schedule keeps the
+configuration as given, and its ``segment_sum`` mean.
+
 ``assert_host_parity`` checks the device runner's per-epoch residual-miss
 lane counts against the host-sim ``RapidGNNRunner``'s ``cache_misses``
 batch-exact on the identical schedule (DESIGN.md §7).
@@ -53,9 +61,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
-from repro.core.schedule import WorkerSchedule, merge_pad_bounds
+from repro.core.schedule import (EpochSchedule, WorkerSchedule,
+                                 merge_pad_bounds)
 from repro.fault.inject import TransientFault, fault_point
-from repro.models.gnn import GNNConfig, init_params
+from repro.models.gnn import GNNConfig, init_params, scatter_free_layers
 from repro.dist.gnn_step import (DeviceCache, DeviceView,
                                  collate_device_epoch, empty_caches,
                                  epoch_k_max, epoch_k_max_split,
@@ -68,6 +77,52 @@ from repro.train.checkpoint import save_run_state
 def _span(name: str) -> jax.profiler.TraceAnnotation:
     """A host span ``rapidgnn.<name>`` on the profiler's clock."""
     return jax.profiler.TraceAnnotation("rapidgnn." + name)
+
+
+def epoch_fanouts(es: EpochSchedule) -> Optional[List[int]]:
+    """Per layer, the fan-out ``F`` with exactly ``num_dst * F`` edges in
+    every batch of the epoch (0 where no batch has a dst row), or None
+    where some layer has no such ``F``."""
+    flat = es.flat
+    out = []
+    for l in range(flat.num_layers):
+        nd = flat.num_dst[l]
+        ne = np.diff(flat.edge_starts[l])
+        live = np.flatnonzero(nd > 0)
+        fo = int(ne[live[0]] // nd[live[0]]) if live.size else 0
+        if (live.size and fo < 1) or not np.array_equal(ne, nd * fo):
+            return None
+        out.append(fo)
+    return out
+
+
+def _merge_fanouts(a: Optional[List[int]], b: Optional[List[int]]
+                  ) -> Optional[List[int]]:
+    """Two ``epoch_fanouts`` results -> the one fan-out per layer they
+    agree on (0 where neither saw a dst row), or None."""
+    if a is None or b is None or len(a) != len(b):
+        return None
+    if any(x and y and x != y for x, y in zip(a, b)):
+        return None
+    return [x or y for x, y in zip(a, b)]
+
+
+def _program_config(cfg: GNNConfig, fanouts: Optional[List[int]]
+                     ) -> GNNConfig:
+    """The epoch program's configuration: ``cfg`` with the fan-outs a
+    regular schedule showed (``_merge_fanouts`` over every (worker,
+    epoch)) where it has none, ``cfg`` itself where the schedule is
+    irregular or a layer saw no dst row. A configuration that states
+    fan-outs must state the schedule's."""
+    if fanouts is None or not all(fanouts):
+        return cfg
+    fanouts = tuple(fanouts)
+    if cfg.fanouts is None:
+        return dataclasses.replace(cfg, fanouts=fanouts)
+    if tuple(cfg.fanouts[:len(fanouts)]) != fanouts:
+        raise ValueError(f"cfg.fanouts {tuple(cfg.fanouts)} disagree with "
+                         f"the schedule's fan-outs {fanouts}")
+    return cfg
 
 
 class StagingError(RuntimeError):
@@ -117,6 +172,9 @@ class DeviceEpochReport:
     local_rows: int = 0
     cache_rows: int = 0
     pulled_rows: int = 0
+    #: layers whose aggregation the epoch program computes as a gather
+    #: and a reduce over the fan-out axis, with no scatter in the forward
+    scatter_free_layers: int = 0
 
     @property
     def total_miss_lanes(self) -> int:
@@ -152,6 +210,7 @@ class DeviceEpochReport:
                 "local_rows": int(self.local_rows),
                 "cache_rows": int(self.cache_rows),
                 "pulled_rows": int(self.pulled_rows),
+                "scatter_free_layers": int(self.scatter_free_layers),
                 "losses": [float(x) for x in self.losses],
                 "accs": [float(x) for x in self.accs],
                 "wall_time_s": float(self.wall_time_s),
@@ -233,8 +292,12 @@ class _DeviceRunnerBase:
         # cross-host DCN tier; flat: k_max is the single-tier bound and
         # k_max_inter stays 1 (unused)
         self.num_steps, self.k_max, self.k_max_inter = 0, 1, 1
+        fanouts: Optional[List[int]] = [0] * cfg.num_layers
         for e in range(self.num_epochs):
             es_list = [ws.epoch(e) for ws in self.schedules]
+            for es in es_list:
+                if es.num_batches:
+                    fanouts = _merge_fanouts(fanouts, epoch_fanouts(es))
             # ids-only cache view: the lane bound never touches feats
             ids_only = self._caches_for(es_list, ids_only=True)
             self.num_steps = max(self.num_steps,
@@ -247,6 +310,13 @@ class _DeviceRunnerBase:
             else:
                 self.k_max = max(self.k_max,
                                  epoch_k_max(es_list, ids_only, self.dv))
+        self.cfg = _program_config(cfg, fanouts)
+        self.scatter_free_layers = scatter_free_layers(self.cfg,
+                                                       self.edge_max)
+        # a program that reads every layer's dst rows off the regular
+        # layout never reads edge_dst: the stage neither builds nor
+        # ships it
+        self.with_edge_dst = self.scatter_free_layers < cfg.num_layers
 
         self.trace_count = 0
         self._fn = jax.jit(self._counted(self._make_epoch_fn()))
@@ -296,11 +366,13 @@ class _DeviceRunnerBase:
         peer is same-host); hierarchical splits by tier, and the tiers
         sum to exactly what the flat plan would count -- the byte-sum
         identity ``verify`` pins (DESIGN.md §6.7)."""
+        counted: Dict[str, int] = {}
         with _span("stage.collate"):
             batches = collate_device_epoch(
                 es_list, caches, self.dv, self.labels, self.batch_size,
                 self.m_max, self.edge_max, k_max, self.num_steps,
-                topology=self.topo, k_max_inter=k_max_inter)
+                topology=self.topo, k_max_inter=k_max_inter,
+                with_edge_dst=self.with_edge_dst, stats=counted)
         rows = batches["input_nodes"]
         # padded rows the program's all_to_alls move: the pipelined epoch
         # issues one extra pull (the pre-scan pulled0; its final wrap pull
@@ -324,13 +396,10 @@ class _DeviceRunnerBase:
             wire_inter = 0
         with _span("stage.to_device"):
             dev = jax.tree.map(jnp.asarray, batches)
-        # rows by assembly source: the ownership test base <= row <
-        # base + n_per against each worker's base, one pull lane per
-        # pulled row, the hot set the rest
-        base = self.dv.offsets.reshape(1, -1, 1)
-        valid = int(np.count_nonzero(rows >= 0))
-        local = int(np.count_nonzero((rows >= base)
-                                     & (rows < base + self.dv.n_per)))
+        # rows by assembly source: the ownership test (the collation's
+        # ``local_rows``), one pull lane per pulled row, the hot set the
+        # rest
+        valid, local = counted["valid_rows"], counted["local_rows"]
         pulled = int((intra + inter).sum())
         return {
             "batches": dev,
@@ -507,6 +576,7 @@ class _DeviceRunnerBase:
                         local_rows=staged["local_rows"],
                         cache_rows=staged["cache_rows"],
                         pulled_rows=staged["pulled_rows"],
+                        scatter_free_layers=self.scatter_free_layers,
                         losses=losses, accs=accs,
                         wall_time_s=time.perf_counter() - t0,
                         stage_s=(nxt["stage_s"] if nxt is not None

@@ -169,7 +169,8 @@ def run_host_cell(spec: CellSpec, worker: int = 0,
 
     cfg = GNNConfig(kind="gcn" if spec.system == "gcn" else "sage",
                     in_dim=g.feat_dim, hidden_dim=spec.hidden,
-                    num_classes=g.num_classes, num_layers=len(fanouts))
+                    num_classes=g.num_classes, num_layers=len(fanouts),
+                    fanouts=tuple(fanouts))
     opt = AdamW(lr=3e-3)
     step = make_train_step(cfg, opt) if spec.train else None
 
@@ -384,7 +385,8 @@ def build_device_runner(spec: CellSpec, sc: dict):
     g = sc["g"]
     cfg = GNNConfig(kind="sage", in_dim=g.feat_dim,
                     hidden_dim=spec.hidden, num_classes=g.num_classes,
-                    num_layers=len(spec.fanouts))
+                    num_layers=len(spec.fanouts),
+                    fanouts=tuple(spec.fanouts))
     topo = spec.topology_obj()
     cls = DeviceRapidGNNRunner if spec.is_rapid else DeviceBaselineRunner
     return cls(sc["schedules"], sc["dv"], cfg, AdamW(lr=3e-3),

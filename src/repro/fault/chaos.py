@@ -87,7 +87,8 @@ class _Chaos:
         self.cfg = GNNConfig(kind="sage", in_dim=self.g.feat_dim,
                              hidden_dim=32,
                              num_classes=self.g.num_classes,
-                             num_layers=2)
+                             num_layers=2,
+                             fanouts=tuple(self.sampler.fanouts))
         self.opt = AdamW(lr=3e-3)
         self.step = make_train_step(self.cfg, self.opt)
         self._init_params = init_params
@@ -155,7 +156,8 @@ class _ServeChaos:
         self.cfg = GNNConfig(kind="sage", in_dim=self.g.feat_dim,
                              hidden_dim=32,
                              num_classes=self.g.num_classes,
-                             num_layers=2)
+                             num_layers=2,
+                             fanouts=tuple(self.sampler.fanouts))
         self.params = init_params(self.cfg, jax.random.key(42))
         n = self.N_PHASES * self.PHASE_REQS
         self.streams = [

@@ -54,7 +54,8 @@ def main() -> None:
     sampler = KHopSampler(g, fanouts=args.fanouts,
                           batch_size=args.batch_size)
     cfg = GNNConfig(kind="sage", in_dim=g.feat_dim, hidden_dim=64,
-                    num_classes=g.num_classes, num_layers=len(args.fanouts))
+                    num_classes=g.num_classes, num_layers=len(args.fanouts),
+                    fanouts=tuple(args.fanouts))
     params = init_params(cfg, jax.random.key(args.seed))
     svc = GNNInferenceService(
         pg, sampler, cfg, params, s0=args.seed, worker=args.worker,

@@ -40,7 +40,8 @@ def run_gnn(args) -> None:
                         num_epochs=args.epochs, n_hot=args.n_hot)
 
     cfg = GNNConfig(kind=args.model, in_dim=g.feat_dim, hidden_dim=256,
-                    num_classes=g.num_classes, num_layers=2)
+                    num_classes=g.num_classes, num_layers=2,
+                    fanouts=tuple(sampler.fanouts))
     params = init_params(cfg, jax.random.key(args.seed))
     opt = AdamW(lr=3e-3)
     opt_state = opt.init(params)

@@ -4,18 +4,21 @@ models; GAT: Veličković et al., arXiv 1710.10903).
 The forward consumes the static-shape ``CollatedBatch`` layout: a padded
 input-node feature matrix ``h`` of shape (m_max, d) whose *dst prefix*
 property (dst nodes of every layer are a prefix of its src nodes, and the
-final seeds are ``h[:batch_size]``) lets all layers update the same
-buffer.
+final seeds are ``h[:batch_size]``) lets each layer compute only the
+leading rows the next one reads.
 
-Aggregation dispatches per ``GNNConfig.agg_backend``: the default
-``"segment"`` is masked ``segment_sum`` over the padded edge lists (the
-oracle and CPU path); ``"pallas"`` / ``"pallas_interpret"`` run the fused
-``kernels/gather_agg`` Pallas kernel, which exploits the deterministic
-sampler's dst-major fan-out-regular edge layout (every dst owns exactly
-``fanout`` contiguous edges, so the padded tail starts on a row boundary
-and aggregates to zero) -- ``cfg.fanouts`` must then carry the per-layer
-fan-outs. The kernel path has a custom VJP, so ``loss_fn`` grads work on
-every backend.
+The sampler's edges are dst-major and fan-out-regular: every dst row owns
+exactly ``fanout`` contiguous edges, zero-degree and padded edges are
+masked, so edge ``e`` belongs to dst row ``e // fanout``. When
+``cfg.fanouts`` carries the per-layer fan-outs, SAGE/GCN's mean is a
+gather, a masked sum over an ``(E // fanout, fanout)`` reshape and a
+divide (``fanout_mean``), over the dst prefix only: no
+scatter in the forward, and each layer updates its ``E // fanout`` dst
+rows. Without fan-outs it is a masked ``segment_sum`` into every row, the
+oracle for any edge list. ``agg_backend`` ``"pallas"`` /
+``"pallas_interpret"`` run the fused ``kernels/gather_agg`` Pallas kernel
+on the same layout instead (custom VJP, so ``loss_fn`` grads work on
+every backend).
 
 ``gat`` (``_gat_forward``, DESIGN.md §3.1) runs on the jnp path only and
 relies on that same layout: each dst row's softmax runs over its
@@ -43,11 +46,13 @@ class GNNConfig:
     num_classes: int
     num_layers: int
     dropout: float = 0.0      # (dry-run/CPU benches run deterministic)
-    #: per-layer sampler fan-outs (input->output); required by the
-    #: pallas aggregation backends (dst-major regular layout contract)
+    #: per-layer sampler fan-outs (input->output): the dst-major regular
+    #: layout contract, which the jnp mean's reshape path, GAT and the
+    #: pallas aggregation backends rely on
     fanouts: Optional[Tuple[int, ...]] = None
-    #: "segment" (jnp segment_sum oracle) | "pallas" (fused gather_agg
-    #: kernel) | "pallas_interpret" (kernel body interpreted on CPU)
+    #: "segment" (jnp: the reshape mean where ``fanouts`` is known, else
+    #: the segment_sum oracle) | "pallas" (fused gather_agg kernel) |
+    #: "pallas_interpret" (kernel body interpreted on CPU)
     agg_backend: str = "segment"
     #: attention heads (``gat`` only): hidden layers are
     #: ``heads * hidden_dim`` wide, the last averages its heads
@@ -139,9 +144,9 @@ def _gat_layer_params(cfg: GNNConfig, l: int, key: jax.Array
 def aggregate_mean(h: jnp.ndarray, edge_src: jnp.ndarray,
                    edge_dst: jnp.ndarray, edge_mask: jnp.ndarray,
                    num_segments: int) -> jnp.ndarray:
-    """Masked mean of src features into dst slots (the paper's AGG).
-    The jnp oracle; ``_aggregate`` may dispatch to the fused Pallas
-    kernel instead."""
+    """Masked mean of src features into dst slots (the paper's AGG) by
+    ``segment_sum``, for any edge list: the oracle, and ``_aggregate``'s
+    path where the fan-outs are unknown."""
     msg = h[edge_src] * edge_mask[:, None].astype(h.dtype)
     summed = jax.ops.segment_sum(msg, edge_dst, num_segments=num_segments)
     cnt = jax.ops.segment_sum(edge_mask.astype(h.dtype), edge_dst,
@@ -149,19 +154,67 @@ def aggregate_mean(h: jnp.ndarray, edge_src: jnp.ndarray,
     return summed / jnp.maximum(cnt, 1.0)[:, None]
 
 
+def fanout_mean(h: jnp.ndarray, edge_src: jnp.ndarray,
+                edge_mask: jnp.ndarray, nd: int, fanout: int) -> jnp.ndarray:
+    """Masked mean over the dst-major, fan-out-regular layout: edge ``e``
+    of the ``nd * fanout`` belongs to dst row ``e // fanout``. h (m, d)
+    -> (nd, d), a gather and a sum over the fan-out axis, no scatter.
+
+    The rows are gathered fan-out-major, into ``(fanout, n8, d)`` with
+    the dst rows padded (masked) to ``n8``, a multiple of 8: the flat
+    gather's output then takes that shape without a copy on a TPU's
+    (8, 128) tiles, and the sum over the fan-out adds whole ``(n8, d)``
+    slabs. Gathered dst-major, as ``(nd, fanout, d)``, a fan-out that is
+    no multiple of 8 (25) makes the compiler copy every gathered row
+    into a padded layout before the sum."""
+    n8 = -(-nd // 8) * 8
+    pad = ((0, n8 - nd), (0, 0))
+    src = jnp.pad(edge_src.reshape(nd, fanout), pad).T
+    msk = jnp.pad(edge_mask.reshape(nd, fanout), pad).T.astype(h.dtype)
+    s = (h[src] * msk[..., None]).sum(axis=0)     # (n8, d)
+    cnt = jnp.maximum(msk.sum(axis=0), 1.0)
+    return (s / cnt[:, None])[:nd]
+
+
+def _reshape_rows(cfg: GNNConfig, layer: int, E: int, rows: int) -> int:
+    """Dst rows of layer ``layer``'s mean on the reshape path:
+    ``E // fanout`` of its ``E`` padded edges, at most its ``rows``
+    source rows (the dst rows are a prefix of them). Valid edges are
+    ``num_dst * fanout <= E``, so the edges past ``nd * fanout`` are
+    padding. 0 where the path does not apply: fan-out unknown, a Pallas
+    backend, or fewer edges than one fan-out."""
+    if cfg.agg_backend != "segment" or not cfg.fanouts:
+        return 0
+    return min(E // cfg.fanouts[layer], rows)
+
+
+def scatter_free_layers(cfg: GNNConfig, edge_max: Sequence[int]) -> int:
+    """Layers whose forward aggregation is a gather and a reduce over
+    the fan-out axis, with no scatter, at padded edge counts
+    ``edge_max``: every GAT layer, and each SAGE/GCN layer on the
+    reshape path."""
+    if cfg.kind == "gat":
+        return cfg.num_layers
+    return sum(_reshape_rows(cfg, l, E, E) > 0
+               for l, E in enumerate(edge_max[:cfg.num_layers]))
+
+
 def _aggregate(cfg: GNNConfig, layer: int, h: jnp.ndarray,
                edge_src: jnp.ndarray, edge_dst: jnp.ndarray,
-               edge_mask: jnp.ndarray, m: int) -> jnp.ndarray:
-    """Backend switch for the AGG: fused ``gather_agg`` when the config
-    opts in AND the padded edge list honours the fan-out-regular
-    contract (edge count divisible by the layer fan-out; the sampler's
-    dst-major layout with replacement guarantees it), else the
-    ``segment_sum`` oracle. Kernel output covers the dst prefix rows
-    only -- the tail up to ``m`` is zero on both paths (padded dst rows
-    are fully masked). Its ops carry the ``aggregate`` scope."""
+               edge_mask: jnp.ndarray) -> jnp.ndarray:
+    """The AGG of layer ``layer`` -> the mean for the layer's leading
+    rows: its ``nd`` dst rows on the reshape path (``_reshape_rows``),
+    all ``m`` rows of ``h`` on the others (``segment_sum``, or the fused
+    ``gather_agg`` where the config opts in AND the edge count divides
+    by the fan-out), whose rows past the dst prefix are zero (padded dst
+    rows are fully masked). Its ops carry the ``aggregate`` scope."""
+    m, E = h.shape[0], edge_src.shape[0]
     fo = cfg.fanouts[layer] if cfg.fanouts else 0
-    E = edge_src.shape[0]
+    nd = _reshape_rows(cfg, layer, E, m)
     with jax.named_scope("aggregate"):
+        if nd:
+            return fanout_mean(h, edge_src[:nd * fo], edge_mask[:nd * fo],
+                               nd, fo)
         if cfg.agg_backend != "segment" and fo > 0 and E % fo == 0:
             nd = E // fo
             agg = gather_agg(h, edge_src, edge_mask, nd=nd, fanout=fo,
@@ -184,22 +237,30 @@ def forward(cfg: GNNConfig, params: Dict[str, Any],
             features: jnp.ndarray,
             edge_src: Sequence[jnp.ndarray], edge_dst: Sequence[jnp.ndarray],
             edge_mask: Sequence[jnp.ndarray]) -> jnp.ndarray:
-    """-> logits for the whole padded node array; seeds are the prefix."""
+    """-> logits for the whole padded node array; seeds are the prefix.
+    Each layer updates the rows its aggregation covers (the dst prefix
+    on the reshape path), and the last layer's rows are padded with
+    zeros to the ``m`` input rows. A layer on the reshape path, and
+    every GAT layer, reads no ``edge_dst``: it may be ``None`` there."""
     if cfg.kind == "gat":
         return _gat_forward(cfg, params, features, edge_src, edge_mask)
     h = features
     m = features.shape[0]
     for l, layer in enumerate(params["layers"]):
         agg = _aggregate(cfg, l, h, edge_src[l], edge_dst[l],
-                         edge_mask[l], m)
+                         edge_mask[l])
+        nd = agg.shape[0]
+        hd = h if nd == h.shape[0] else h[:nd]
         if cfg.kind == "sage":
-            h_new = (_matmul(cfg, h, layer["w_self"])
+            h_new = (_matmul(cfg, hd, layer["w_self"])
                      + _matmul(cfg, agg, layer["w_neigh"]) + layer["b"])
         else:  # gcn: mean over {self} U neighbors (renormalisation trick)
-            h_new = _matmul(cfg, 0.5 * (h + agg), layer["w"]) + layer["b"]
+            h_new = _matmul(cfg, 0.5 * (hd + agg), layer["w"]) + layer["b"]
         if l < cfg.num_layers - 1:
             h_new = jax.nn.relu(h_new)
         h = h_new
+    if h.shape[0] < m:
+        h = jnp.pad(h, ((0, m - h.shape[0]), (0, 0)))
     return h
 
 

@@ -8,6 +8,8 @@ attends over the sources of its valid in-edges (duplicates count once
 each) and over itself, per head, with LeakyReLU(0.2) scores; hidden
 layers concatenate their heads and apply ELU, the last averages them.
 """
+import dataclasses
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -95,6 +97,9 @@ def runner_case(P, train_per_worker=12, batch=4, fanouts=(2, 2, 2)):
     from repro.graph import KHopSampler, load_dataset, partition_graph
 
     g = load_dataset("tiny")
+    # load_dataset caches: a copy takes the edited train_mask, so tests
+    # that share the cached instance in this process stay unaffected
+    g = dataclasses.replace(g, train_mask=g.train_mask.copy())
     pg = partition_graph(g, P, "greedy")
     mask = np.zeros(g.num_nodes, bool)
     for w in range(P):

@@ -344,6 +344,39 @@ def test_vectorized_collation_padded_steps(sched_case):
     assert (vec["input_nodes"][-3:] == -1).all()
 
 
+@pytest.mark.parametrize("cache_kind", ["hot", "empty"])
+def test_collation_counts_rows_and_leaves_edge_dst_out(sched_case,
+                                                       cache_kind):
+    """``stats`` counts the rows with a node, and those their own
+    worker's shard holds, as the padded ``input_nodes`` shows them;
+    ``with_edge_dst=False`` leaves ``edge_dst`` out and every other
+    array as it was."""
+    g, pg, schedules, dv = sched_case
+    m_max, edge_max = merge_pad_bounds(schedules)
+    es_list = [ws.epoch(0) for ws in schedules]
+    caches = (empty_caches(4, g.feat_dim) if cache_kind == "empty"
+              else [dv.remap_cache(es.cache_ids) for es in es_list])
+    k_max = epoch_k_max(es_list, caches, dv)
+    S = max(es.num_batches for es in es_list) + 1
+    args = (es_list, caches, dv, g.labels, 16, m_max, edge_max, k_max, S)
+    stats = {}
+    full = collate_device_epoch(*args, stats=stats)
+    rows = full["input_nodes"]
+    base = dv.offsets.reshape(1, -1, 1)
+    local = (rows >= base) & (rows < base + dv.n_per)
+    assert stats == {"valid_rows": int((rows >= 0).sum()),
+                     "local_rows": int(local.sum())}
+    assert 0 < stats["local_rows"] < stats["valid_rows"]
+    lean = collate_device_epoch(*args, with_edge_dst=False)
+    assert lean["edge_dst"] == [None] * len(edge_max)
+    for k in ("input_nodes", "labels", "seed_mask", "send_ids",
+              "send_pos", "send_mask"):
+        np.testing.assert_array_equal(lean[k], full[k], err_msg=k)
+    for l in range(len(edge_max)):
+        for k in ("edge_src", "edge_mask"):
+            np.testing.assert_array_equal(lean[k][l], full[k][l])
+
+
 def test_classify_fallback_matches_stamp_table(sched_case, monkeypatch):
     """Id spaces past STAMP_TABLE_MAX_SLOTS take the per-worker binary
     search branch; it must classify identically."""
